@@ -1,9 +1,9 @@
 """Shared helpers for the per-table benchmarks.
 
-CPU container ⇒ no wall-clock TPU numbers. Each benchmark derives its table
-from (a) functional runs of the real system at smoke scale, and (b) the
-compiled dry-run artifacts (experiments/dryrun/*.json) + the v5e roofline
-constants — the methodology mandated by the assignment (§Roofline).
+These benchmarks measure no device time. Each derives its table from (a)
+functional runs of the real system on the smoke config, on whatever device
+JAX finds, and (b) the compiled dry-run artifacts (experiments/dryrun/*.json)
+priced with v5e peak constants, which are estimates, not measurements.
 """
 from __future__ import annotations
 
@@ -33,8 +33,11 @@ def load_dryrun(arch: str, shape: str, mesh: str = "16x16") -> Optional[Dict]:
 
 
 def ensure_dryrun(arch: str, shape: str, mesh: str = "16x16") -> Optional[Dict]:
-    """Load a dry-run record, running it on demand (subprocess: needs 512
-    placeholder devices, which this process must not claim)."""
+    """Load a dry-run record, running it on demand in a child process
+    (it needs 512 placeholder CPU devices; dryrun.py pins the child to the
+    CPU so it never claims an accelerator this process holds). A child
+    that fails raises here instead of leaving the caller on placeholder
+    costs."""
     rec = load_dryrun(arch, shape, mesh)
     if rec is not None:
         return rec
@@ -45,7 +48,12 @@ def ensure_dryrun(arch: str, shape: str, mesh: str = "16x16") -> Optional[Dict]:
     if mesh == "2x16x16":
         cmd.append("--multi-pod")
     env = dict(os.environ, PYTHONPATH=src)
-    subprocess.run(cmd, env=env, capture_output=True, timeout=580)
+    r = subprocess.run(cmd, env=env, capture_output=True, text=True,
+                       timeout=580)
+    if r.returncode != 0:
+        raise RuntimeError(
+            f"dry run {arch} x {shape} x {mesh} exited {r.returncode}:\n"
+            f"{r.stderr[-2000:]}")
     return load_dryrun(arch, shape, mesh)
 
 

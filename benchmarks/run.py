@@ -37,6 +37,8 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     mods = [m for m in MODULES if args.only is None or args.only in m]
     failures = []
     for name in mods:

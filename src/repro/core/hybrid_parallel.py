@@ -33,8 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.configs.base import ModelConfig
 from repro.models.attention import NEG_INF, _pick_chunk
 from repro.models.layers import apply_rope, rms_norm
@@ -131,7 +129,7 @@ def mla_prefill_hybrid(p: dict, x: jax.Array, cfg: ModelConfig, mesh: Mesh,
         return out, latent_loc
 
     wo_spec = P() if oproj_mode == "a2a" else P("model", None)
-    out, latent = shard_map(
+    out, latent = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axis, None),            # x: sequence-sharded
                   P(), P(), P(None, axis),        # wq_a, q_ln, wq_b(heads)

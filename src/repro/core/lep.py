@@ -40,8 +40,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.configs.base import ModelConfig
 from repro.models import moe as moe_mod
 from repro.models.layers import swiglu
@@ -245,7 +243,7 @@ def make_lep_moe_fn(
             dropped = jax.lax.psum(jnp.sum(~in_cap), mesh_axes)
             return out.astype(x_loc.dtype), aux, dropped
 
-        routed, aux, dropped = shard_map(
+        routed, aux, dropped = jax.shard_map(
             body, mesh=mesh,
             in_specs=(tok_spec, P(mesh_axes), P(), w_spec, w_spec, wd_spec),
             out_specs=(tok_spec, P(), P()),

@@ -223,8 +223,8 @@ def fit_draft_head(params: dict, cfg: ModelConfig, mtp: dict, key: jax.Array,
                                        cache_dtype=jnp.float32)
     tok0 = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
     cl0 = jnp.full((n_seq,), prompt_len, jnp.int32)
-    em, _, _, _, _ = model_mod.decode_loop(params, cfg, tok0, caches, cl0,
-                                           gen_len, moe_fn=moe_fn)
+    em = model_mod.decode_loop(params, cfg, tok0, caches, cl0, gen_len,
+                               moe_fn=moe_fn)[0]
     seq = jnp.concatenate([tok0[:, None], em], axis=1)       # (n_seq, G+1)
     cur = seq[:, :-1].reshape(-1)
     nxt = seq[:, 1:].reshape(-1)

@@ -11,9 +11,11 @@ Four kernels, each a package with ``<name>.py`` (pl.pallas_call + BlockSpec),
 * ``dispatch_quant`` — fused per-token INT8 quantize+pack, the producer side
   of FusedDispatch's early quantization (paper §4.2.1).
 
-On this CPU-only container kernels run under ``interpret=True``; on real TPU
-the same pallas_call lowers to Mosaic. All kernels are validated against
-their ``ref.py`` oracles across shape/dtype sweeps in tests/.
+On the CPU kernels run under ``interpret=True``; on a TPU the same
+pallas_call lowers to Mosaic. Tests validate every kernel against its
+``ref.py`` oracle in interpret mode, ``tests/test_chip_compile.py`` compiles
+each for a described v5e at real widths, and ``chip_smoke.py`` runs each on
+the chip.
 """
 
 import jax

@@ -14,8 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import TPUCompilerParams
-
 
 def _kernel(x_ref, q_ref, s_ref):
     x = x_ref[...].astype(jnp.float32)                    # (BT, D)
@@ -44,7 +42,7 @@ def dispatch_quantize_pallas(x, bt: int = 256, interpret: bool = False):
             jax.ShapeDtypeStruct((t, d), jnp.int8),
             jax.ShapeDtypeStruct((t, 1), jnp.float32),
         ],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x)

@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import TPUCompilerParams
-
 
 def _kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref):
     kb = pl.program_id(2)
@@ -61,7 +59,7 @@ def int8_matmul_pallas(x_q, w_q, x_scale, w_scale, out_dtype=jnp.bfloat16,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x_q, w_q, x_scale, w_scale)

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -96,7 +94,7 @@ def mla_decode_attention_pallas(q_lat, q_rope, cache, valid, scale: float,
             pltpu.VMEM((h, 1), jnp.float32),   # running sum
             pltpu.VMEM((h, r), jnp.float32),   # accumulator
         ],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q_lat, q_rope, cache, valid_i)

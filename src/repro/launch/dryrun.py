@@ -1,5 +1,8 @@
 import os
+# 512 placeholder CPU devices; never the accelerator, which another process
+# (the benchmark that started this one) may hold.
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run (deliverable e): lower + compile every
 (architecture × input shape × mesh) combination with ShapeDtypeStruct
@@ -21,7 +24,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import cost_analysis
 from repro.configs import ASSIGNED_ARCHS, get_config, get_shape, INPUT_SHAPES
 from repro.configs.base import InputShape, ModelConfig
 from repro.core.lep import make_lep_moe_fn, pick_lep_plan
@@ -230,7 +232,7 @@ def _measure(cfg, shape, mesh):
     lowered = jax.jit(step, in_shardings=shardings).lower(*args)
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     coll = hlo.collective_bytes(compiled.as_text())
     struct = (getattr(mem, "temp_size_in_bytes", 0)
               + getattr(mem, "argument_size_in_bytes", 0)

@@ -11,16 +11,19 @@ device state (the dry-run must set XLA_FLAGS before first jax init).
 """
 from __future__ import annotations
 
-from repro.compat import AxisType, make_mesh
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
-def make_debug_mesh(n_data: int = 2, n_model: int = 4):
-    """Small mesh for CPU multi-device tests (8 forced host devices)."""
-    return make_mesh((n_data, n_model), ("data", "model"),
-                     axis_types=(AxisType.Auto, AxisType.Auto))
+def make_debug_mesh(n_data: int = 2, n_model: int = 4, devices=None):
+    """Small ("data", "model") mesh: 8 forced host devices in the CPU
+    multi-device tests, or the first ``n_data * n_model`` of ``devices``."""
+    return jax.make_mesh((n_data, n_model), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto),
+                         devices=devices)

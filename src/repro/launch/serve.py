@@ -1,7 +1,7 @@
 """Serving launcher: the full PDC pipeline on a batch of synthetic requests.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b \
-      --n-requests 6 --prompt-len 24 --max-new 8 \
+      --n-requests 6 --prompt-len 24 --max-new 8 [--full] \
       [--mtp [--mtp-fused] [--fit-draft]] [--no-cache] \
       [--hit-aware-admission] \
       [--policy least_loaded|round_robin|queue_depth] \
@@ -24,14 +24,18 @@
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import time
+from typing import List, Optional
 
 import jax
 import numpy as np
 
 from repro.configs import get_config, smoke_variant
+from repro.configs.base import ModelConfig
 from repro.core import init_mtp_params
+from repro.launch.compile_cache import CompileCounter, enable_compile_cache
 from repro.mempool import EMSService, MemoryPool
 from repro.models import init_params
 from repro.serving import Request, ServingSystem
@@ -40,9 +44,24 @@ from repro.serving.pool import DECODE_ROUTERS
 from repro.serving.scheduler import ROUTERS
 
 
-def main() -> None:
+@dataclasses.dataclass
+class Deployment:
+    """One serving deployment as the launcher builds it from its flags."""
+    cfg: ModelConfig
+    params: dict
+    system: ServingSystem
+    requests: List[Request]
+    cache: Optional[EMSService]
+    injector: Optional[FaultInjector]
+    open_loop: bool
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
+    ap.add_argument("--full", action="store_true",
+                    help="serve the registered config as published "
+                         "(default: its reduced smoke variant)")
     ap.add_argument("--n-requests", type=int, default=6)
     ap.add_argument("--prompt-len", type=int, default=24)
     ap.add_argument("--max-new", type=int, default=8)
@@ -180,9 +199,28 @@ def main() -> None:
                     help="graceful degradation: shed any queued admission "
                          "held longer than this many virtual seconds "
                          "(bounds the backlog when capacity is lost)")
-    args = ap.parse_args()
+    return ap
 
-    cfg = smoke_variant(get_config(args.arch))
+
+def capacity_for(args: argparse.Namespace) -> int:
+    """KV slot capacity in tokens: the longest request the flags generate.
+    Production streams draw heavy-tailed lengths up to the generator's clip
+    (256 prompt + 64 output tokens by default): size the slots for the
+    clip, not the medians, so long-tail requests are not all rejected."""
+    if args.production:
+        return 256 + 64 + 8
+    return args.prompt_len + args.max_new + 8
+
+
+def build(args: argparse.Namespace) -> Deployment:
+    """Model, synthetic requests and the wired :class:`ServingSystem` for
+    the launcher's flags. ``launch/serve.py`` and ``chip_smoke.py`` both
+    build through here, so the chip runs the same wiring users get."""
+    if args.production and args.poisson_rate is None:
+        raise ValueError("--production requires --poisson-rate")
+    cfg = get_config(args.arch)
+    if not args.full:
+        cfg = smoke_variant(cfg)
     params = init_params(jax.random.PRNGKey(0), cfg)
     cc = None
     if not args.no_cache:
@@ -194,8 +232,6 @@ def main() -> None:
     shared = min(args.shared_prefix, args.prompt_len - 1)
     open_loop = args.open_loop or args.poisson_rate is not None
     if args.production:
-        if args.poisson_rate is None:
-            ap.error("--production requires --poisson-rate")
         from repro.serving import production_requests
         reqs = production_requests(
             args.n_requests, seed=args.seed, vocab_size=cfg.vocab_size,
@@ -243,16 +279,10 @@ def main() -> None:
         injector = FaultInjector(plan, seed=args.fault_seed)
         print(f"fault plan ({len(plan.events)} events): {plan.to_json()}")
 
-    # Production streams draw heavy-tailed lengths up to the generator's
-    # clip (256 prompt + 64 output tokens by default): size the KV slots
-    # for the clip, not the medians, so long-tail requests are not all
-    # capacity-rejected.
-    capacity = 256 + 64 + 8 if args.production \
-        else args.prompt_len + args.max_new + 8
     system = ServingSystem(params, cfg,
                            prefill_engines=args.prefill_engines,
                            decode_batch=args.decode_batch,
-                           capacity=capacity,
+                           capacity=capacity_for(args),
                            decode_engines=args.decode_engines,
                            decode_router=args.decode_router,
                            decode_rebalance_every=args.rebalance_every,
@@ -289,8 +319,17 @@ def main() -> None:
                            hit_aware_admission=args.hit_aware_admission
                            or None,
                            fault_injector=injector)
+    return Deployment(cfg, params, system, reqs, cc, injector, open_loop)
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+    enable_compile_cache()
+    compiles = CompileCounter()
+    dep = build(args)
+    cfg, system, cc, injector = dep.cfg, dep.system, dep.cache, dep.injector
     t0 = time.time()
-    results = system.serve(reqs, open_loop=open_loop)
+    results = system.serve(dep.requests, open_loop=dep.open_loop)
     dt = time.time() - t0
     total_new = sum(len(r.tokens) for r in results if not r.shed)
     for r in sorted(results, key=lambda r: r.rid):
@@ -298,8 +337,11 @@ def main() -> None:
         print(f"rid={r.rid} prefill@{r.prefill_instance} reused={r.reused_tokens} "
               f"computed={r.computed_tokens} iters={r.decode_iters} "
               f"tokens={r.tokens}{flag}")
-    print(f"\n{len(results)} requests, {total_new} tokens in {dt:.2f}s wall "
-          f"({total_new/dt:.1f} tok/s on CPU smoke config)")
+    dev = jax.devices()[0]
+    print(f"\n{len(results)} requests, {total_new} tokens of {cfg.name} in "
+          f"{dt:.2f}s host wall clock, compilation included, on "
+          f"{len(jax.devices())}x {dev.platform} {dev.device_kind}")
+    print(f"compile: {compiles}")
     summary = system.scheduler.summary()
     classes = summary.pop("classes", None)
     brownout_timeline = summary.pop("brownout_timeline", None)

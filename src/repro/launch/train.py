@@ -1,9 +1,9 @@
 """Training launcher.
 
-CPU (this container): reduced smoke-scale runs. TPU: the same step is pjit'ed
-over make_production_mesh() with the sharding rules in sharding.py; enable
-``--xla_tpu_enable_latency_hiding_scheduler=true`` for the microbatch overlap
-(core/microbatch.py).
+Runs the jitted train step on one device: by default the reduced smoke
+config, with ``--full`` the config as published. No mesh is built here; the
+sharding rules in sharding.py are exercised only by the dry-run compile
+(launch/dryrun.py).
 
   PYTHONPATH=src python -m repro.launch.train --arch olmoe-1b-7b --steps 50 \
       --batch 8 --seq 64 [--smoke/--full] [--n-micro 2]

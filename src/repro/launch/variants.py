@@ -1,5 +1,8 @@
 import os
+# 512 placeholder CPU devices; never the accelerator, which another process
+# (the benchmark that started this one) may hold.
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """§Perf hillclimbing harness: lower+compile named VARIANTS of a
 (arch × shape) pair and report roofline-term deltas vs baseline.

@@ -553,7 +553,7 @@ def decode_loop(params: dict, cfg: ModelConfig, tokens: jax.Array,
                 *, steps_left: Optional[jax.Array] = None,
                 moe_fn: Optional[MoeFn] = None,
                 step_fn: Optional[Callable] = None
-                ) -> Tuple[jax.Array, jax.Array, jax.Array,
+                ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array,
                            Dict[str, Any], jax.Array]:
     """``n_steps`` greedy decode iterations in one ``lax.scan`` — N tokens
     per host sync instead of one.
@@ -574,8 +574,10 @@ def decode_loop(params: dict, cfg: ModelConfig, tokens: jax.Array,
     ``(tokens (B,1), caches, cache_len) -> (logits, caches)`` step — the
     hook the microbatch interleaver wraps.
 
-    Returns ``(emitted (B, n_steps), live (B, n_steps), tokens (B,), caches,
-    cache_len)``; ``emitted[:, j]`` is meaningful only where ``live[:, j]``.
+    Returns ``(emitted (B, n_steps), live (B, n_steps), finite (B, n_steps),
+    tokens (B,), caches, cache_len)``; ``emitted[:, j]`` is meaningful only
+    where ``live[:, j]``; ``finite[:, j]`` says step j's logits row had no
+    NaN or Inf.
     Chunk-split invariance: because frozen slots hold bit-exactly and live
     slots see the identical per-step computation, any partition of N total
     iterations into scan dispatches emits identical tokens.
@@ -619,11 +621,12 @@ def decode_loop(params: dict, cfg: ModelConfig, tokens: jax.Array,
         ncs = jax.tree.map(
             lambda n, o, ax: _select(live, n, o, ax), ncs, cs, axes)
         ncs = _with_lengths(cfg, ncs, cl)
-        return (tok, cl, left, ncs), (nxt, live)
+        fin = jnp.isfinite(logits).all(axis=-1)
+        return (tok, cl, left, ncs), (nxt, live, fin)
 
-    (tokens, cache_len, _, caches), (em, lv) = jax.lax.scan(
+    (tokens, cache_len, _, caches), (em, lv, fin) = jax.lax.scan(
         body, (tokens, cache_len, steps_left, caches), None, length=n_steps)
-    return em.T, lv.T, tokens, caches, cache_len
+    return em.T, lv.T, fin.T, tokens, caches, cache_len
 
 
 # ---------------------------------------------------------------------------

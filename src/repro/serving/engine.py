@@ -16,8 +16,9 @@ interfaces:
   this class only moves tensors: run prefill, hand KV off over the
   RDMA-plane transfer engine, insert into decode slots, step decode.
 
-Everything runs functionally on CPU with smoke configs; on TPU the same
-step functions are pjit-ed over the production mesh (launch/serve.py).
+Every engine jits its steps for one device: the CPU in the tests (smoke
+configs), one TPU chip in ``chip_smoke.py`` (published widths). No serving
+code builds a mesh.
 """
 from __future__ import annotations
 
@@ -71,6 +72,9 @@ class RequestResult:
     decode_iters: int = 0
     shed: bool = False
     slo_class: str = "interactive"
+    # Served logits rows with a NaN or Inf: the prompt's last position and
+    # every greedy decode step (MTP steps are not inspected).
+    nonfinite_logits: int = 0
 
 
 # ---------------------------------------------------------------------------
@@ -167,6 +171,15 @@ class PrefillEngine:
 
     def run(self, req: Request) -> Tuple[int, Any, RequestResult]:
         """Process one prompt. Returns (first_token, caches(B=1), result)."""
+        last, caches, res = self.run_logits(req)
+        first, finite = jax.device_get((jnp.argmax(last),
+                                        jnp.isfinite(last).all()))
+        res.nonfinite_logits += int(not finite)
+        return int(first), caches, res
+
+    def run_logits(self, req: Request) -> Tuple[jax.Array, Any, RequestResult]:
+        """:meth:`run`, returning the prompt's last-position logits (V,)
+        in place of the greedy first token."""
         cfg = self.cfg
         prompt = list(req.prompt)
         res = RequestResult(req.rid, [], prefill_instance=self.instance_id)
@@ -215,7 +228,6 @@ class PrefillEngine:
                     last, caches, _ = self._continue_chunks(
                         prompt[reuse_len:], caches, reuse_len,
                         self.suffix_chunk, fresh=False)
-                first = int(jnp.argmax(last))
                 res.computed_tokens = len(prompt) - reuse_len
             elif self.prefill_chunk and self._chunkable:
                 # Fresh prompt, bounded compile shapes: the whole prompt
@@ -226,12 +238,11 @@ class PrefillEngine:
                 caches = self._fresh_cache()
                 last, caches, _ = self._continue_chunks(
                     prompt, caches, 0, self.prefill_chunk, fresh=True)
-                first = int(jnp.argmax(last))
                 res.computed_tokens = len(prompt)
             else:
                 batch = {"tokens": jnp.asarray([prompt], jnp.int32)}
                 logits, caches = self._prefill(self.params, batch)
-                first = int(jnp.argmax(logits[0, len(prompt) - 1]))
+                last = logits[0, len(prompt) - 1]
                 res.computed_tokens = len(prompt)
             res.reused_tokens = reuse_len
 
@@ -245,7 +256,7 @@ class PrefillEngine:
                 if payloads:
                     self.cc.store(prompt[: n_blocks * self.cc.block],
                                   payloads, engine=self._ems_tag)
-            return first, caches, res
+            return last, caches, res
         finally:
             self.load -= len(prompt)
 
@@ -492,13 +503,15 @@ class DecodeEngine:
             self.cur_tok, self.draft_tok = x_next, d_next
             em = np.asarray(emitted)
             acc = np.asarray(accepted)
+            fin = np.ones(self.b, bool)
         else:
             logits, self.caches = self._step(self.params, self.cur_tok[:, None],
                                              self.caches, self.cache_len)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             self.cache_len = self.cache_len + 1
             self.cur_tok = nxt
-            em = np.asarray(nxt)[:, None]
+            em, fin = jax.device_get((nxt[:, None],
+                                      jnp.isfinite(logits).all(axis=-1)))
             acc = np.zeros(self.b, bool)
 
         finished = []
@@ -506,6 +519,7 @@ class DecodeEngine:
         for i, info in list(self.slot_mgr.active_slots()):
             slot: _Slot = info.payload
             slot.result.decode_iters += 1
+            slot.result.nonfinite_logits += int(not fin[i])
             # Mirror the device-side cache growth (MTP appends the accepted
             # draft token too) with capacity enforcement.
             self.slot_mgr.advance(i, 2 if (self.use_mtp and acc[i]) else 1)
@@ -544,11 +558,10 @@ class DecodeEngine:
         for i, info in self.slot_mgr.active_slots():
             left[i] = min(info.payload.remaining, width)
             resident[i] = info.rid
-        emitted, live, self.cur_tok, self.caches, self.cache_len = \
+        emitted, live, finite, self.cur_tok, self.caches, self.cache_len = \
             self._get_loop(width)(self.params, self.cur_tok, self.caches,
                                   self.cache_len, jnp.asarray(left))
-        em = np.asarray(emitted)
-        lv = np.asarray(live)
+        em, lv, fin = jax.device_get((emitted, live, finite))
 
         finished: List[RequestResult] = []
         iter_log: List[Tuple[List[int], List[int], dict, List[int]]] = []
@@ -565,6 +578,7 @@ class DecodeEngine:
                 info = self.slot_mgr.get(i)   # live => not yet released
                 slot: _Slot = info.payload
                 slot.result.decode_iters += 1
+                slot.result.nonfinite_logits += int(not fin[i, j])
                 self.slot_mgr.advance(i, 1)
                 slot.result.tokens.append(int(em[i, j]))
                 slot.remaining -= 1

@@ -1,0 +1,159 @@
+"""Compiles for a described TPU v5e (no chip attached) at real widths.
+
+What interpret mode cannot show, the chip's compiler refuses here: a block
+not aligned to the tiling, more VMEM than a kernel may use, a program that
+does not fit the device. Nothing runs, so these tests say nothing about
+results or time.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and every xdist worker
+imports this file.
+"""
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+
+import chip_smoke  # noqa: E402
+from repro.configs import get_config  # noqa: E402
+from repro.launch import serve  # noqa: E402
+from repro.models import model as model_mod  # noqa: E402
+from repro.serving.engine import PrefillEngine  # noqa: E402
+
+#: HBM one v5e chip lets a program use (the figure the compiler's own
+#: out-of-memory message gives, 15.75 of 16 GiB).
+HBM_BYTES = int(15.75 * 2**30)
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # A compile for a described chip can be written to the persistent
+    # cache but never read back here; keep it out of any cache in use.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _on(tree, sharding):
+    return jax.tree.map(lambda a: _spec(a.shape, a.dtype, sharding), tree)
+
+
+def _pallas_fn(name):
+    """The kernel's pallas_call wrapper, with chip_smoke's static args."""
+    import functools
+
+    from repro.kernels.dispatch_quant.dispatch_quant import \
+        dispatch_quantize_pallas
+    from repro.kernels.int8_gemm.int8_gemm import int8_matmul_pallas
+    from repro.kernels.mla_attention.mla_attention import \
+        mla_decode_attention_pallas
+    from repro.kernels.ssd_scan.ssd_scan import ssd_scan_pallas
+    fn = {"mla_decode_attention": mla_decode_attention_pallas,
+          "int8_matmul": int8_matmul_pallas, "ssd_scan": ssd_scan_pallas,
+          "dispatch_quantize": dispatch_quantize_pallas}[name]
+    return functools.partial(fn, **chip_smoke.KERNEL_KW.get(name, {}))
+
+
+@pytest.mark.parametrize("name", ["mla_decode_attention", "int8_matmul",
+                                  "ssd_scan", "dispatch_quantize"])
+def test_kernel_compiles_for_v5e(one_chip, name):
+    """Each kernel at the widths chip_smoke runs it."""
+    shapes = jax.eval_shape(chip_smoke.kernel_inputs, jax.random.PRNGKey(0))
+    args = _on(shapes[name], one_chip)
+    compiled = jax.jit(_pallas_fn(name)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("program", ["decode_step", "decode_loop", "prefill",
+                                     "prefill_continue"])
+def test_granite_serve_programs_fit_one_v5e(one_chip, program):
+    """Each full-width program chip_smoke's deployment compiles, at its
+    batch and capacity, with caches donated as the engines donate them,
+    fits one chip next to every request's B=1 prefill cache (a closed-loop
+    wave holds them all until decode admits them)."""
+    args = serve.build_parser().parse_args(list(chip_smoke.SERVE_ARGV))
+    cfg = get_config(args.arch)
+    batch, cap = args.decode_batch, serve.capacity_for(args)
+    params = _on(jax.eval_shape(lambda k: model_mod.init_params(k, cfg),
+                                jax.random.PRNGKey(0)), one_chip)
+
+    def caches(b):
+        return _on(jax.eval_shape(lambda: model_mod.make_caches(
+            cfg, b, cap, jnp.float32)), one_chip)
+
+    rows = _spec((batch,), jnp.int32, one_chip)
+    if program == "decode_step":
+        fn = jax.jit(lambda p, t, c, n: model_mod.decode_step(p, cfg, t, c, n),
+                     donate_argnums=(2,))
+        lowered = fn.lower(params, _spec((batch, 1), jnp.int32, one_chip),
+                           caches(batch), rows)
+    elif program == "decode_loop":
+        fn = jax.jit(lambda p, t, c, n, left: model_mod.decode_loop(
+            p, cfg, t, c, n, args.decode_chunk, steps_left=left),
+            donate_argnums=(2,))
+        lowered = fn.lower(params, rows, caches(batch), rows, rows)
+    elif program == "prefill":
+        fn = jax.jit(lambda p, t: model_mod.prefill(
+            p, cfg, {"tokens": t}, cap, cache_dtype=jnp.float32))
+        lowered = fn.lower(params, _spec((1, args.prompt_len), jnp.int32,
+                                         one_chip))
+    else:
+        width = PrefillEngine.SUFFIX_CHUNK
+        fn = jax.jit(lambda p, t, c, off: model_mod.prefill_continue(
+            p, cfg, t, c, off), donate_argnums=(2,))
+        lowered = fn.lower(params, _spec((1, width), jnp.int32, one_chip),
+                           caches(1), _spec((), jnp.int32, one_chip))
+    mem = lowered.compile().memory_analysis()
+    held = args.n_requests * sum(
+        a.size * a.dtype.itemsize for a in jax.tree.leaves(caches(1)))
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes + held
+    assert used < HBM_BYTES, (mem.argument_size_in_bytes,
+                              mem.temp_size_in_bytes, held)
+
+
+@pytest.mark.parametrize("tokens", [128, 8192])
+@pytest.mark.parametrize("quantize", [True, False])
+def test_lep_moe_on_four_v5e_has_two_all_to_alls(topo, tokens, quantize):
+    """olmoe-1b-7b widths, experts sharded over "model" of a (1, 4) mesh:
+    one all-to-all to dispatch (scales packed into the int8 payload when
+    quantized) and one to combine."""
+    from repro.core.lep import make_lep_moe_fn
+    from repro.models import moe as moe_mod
+
+    cfg = chip_smoke.lep_config()
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(1, 4), ("data", "model"))
+    expert, repl = NamedSharding(mesh, P("model")), NamedSharding(mesh, P())
+    p1 = jax.eval_shape(lambda k: moe_mod.init_moe_params(
+        k, cfg, 1, jnp.bfloat16), jax.random.PRNGKey(0))
+    p = {k: _spec(v.shape[1:], v.dtype, expert if k.startswith("w_") else repl)
+         for k, v in p1.items()}
+    x = _spec((tokens, cfg.d_model), jnp.bfloat16, repl)
+    fn = make_lep_moe_fn(mesh, ep_axes=("model",), quantize=quantize)
+    compiled = jax.jit(lambda pp, xx: fn(pp, xx, cfg)).lower(p, x).compile()
+    assert chip_smoke.count_all_to_all(compiled.as_text()) == 2

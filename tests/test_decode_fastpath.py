@@ -74,9 +74,11 @@ def test_decode_loop_matches_sequential(arch):
     _, tok0, caches, cl0 = _prefill_batch(cfg, params)
     n = 4
     seq, caches_s, _ = _sequential(cfg, params, tok0, caches, cl0, n)
-    em, lv, _, caches_l, clf = decode_loop(params, cfg, tok0, caches, cl0, n)
+    em, lv, fin, _, caches_l, clf = decode_loop(params, cfg, tok0, caches,
+                                                cl0, n)
     assert np.array_equal(np.asarray(em), seq)
     assert np.asarray(lv).all()
+    assert np.asarray(fin).all()
     assert np.array_equal(np.asarray(clf), np.asarray(cl0) + n)
     assert _content_equal(caches_s, caches_l)
 
@@ -86,7 +88,7 @@ def test_decode_loop_per_slot_masking(qwen):
     cfg, params = qwen
     _, tok0, caches, cl0 = _prefill_batch(cfg, params)
     seq, _, _ = _sequential(cfg, params, tok0, caches, cl0, 5)
-    em, lv, _, caches_m, clm = decode_loop(
+    em, lv, _, _, caches_m, clm = decode_loop(
         params, cfg, tok0, caches, cl0, 5,
         steps_left=jnp.asarray([5, 2], jnp.int32))
     em, lv = np.asarray(em), np.asarray(lv)
@@ -110,7 +112,7 @@ def test_decode_loop_capacity_masking(qwen):
     """Slots at cache capacity stop advancing instead of corrupting KV."""
     cfg, params = qwen
     _, tok0, caches, cl0 = _prefill_batch(cfg, params, capacity=14)  # 2 free
-    em, lv, _, _, clf = decode_loop(params, cfg, tok0, caches, cl0, 5)
+    em, lv, _, _, _, clf = decode_loop(params, cfg, tok0, caches, cl0, 5)
     assert np.asarray(clf).tolist() == [14, 14]
     assert np.asarray(lv)[:, :2].all() and not np.asarray(lv)[:, 2:].any()
 
@@ -123,8 +125,8 @@ def test_decode_loop_interleaved_matches_sequential(qwen):
         lambda t, c, l: decode_step(params, cfg, t, c, l), 2)
     seq, caches_s, _ = _sequential(cfg, params, tok0, caches, cl0, 4,
                                    step=wrap)
-    em, lv, _, caches_l, _ = decode_loop(params, cfg, tok0, caches, cl0, 4,
-                                         step_fn=wrap)
+    em, lv, _, _, caches_l, _ = decode_loop(params, cfg, tok0, caches, cl0,
+                                            4, step_fn=wrap)
     assert np.array_equal(np.asarray(em), seq)
     assert _content_equal(caches_s, caches_l)
 
@@ -219,6 +221,24 @@ def test_serving_decode_chunk_token_identical(qwen):
         assert out[4][rid].decode_iters == out[1][rid].decode_iters
     # virtual decode time must be charged per iteration, not per chunk
     assert not out[4][0].shed
+
+
+@pytest.mark.parametrize("chunk", [1, 4])
+def test_serving_counts_nonfinite_logits(qwen, chunk):
+    """Every served logits row is inspected, on the per-step and the scanned
+    decode path: none with finite weights; all of them (the prompt's last
+    position and each decode step) with a NaN final norm, which leaves the
+    KV caches, and so decoding itself, intact."""
+    cfg, params = qwen
+    reqs = [Request(i, list(range(1 + i, 13 + i)), 6) for i in range(3)]
+    nan_norm = dict(params, final_norm=jnp.full_like(params["final_norm"],
+                                                     jnp.nan))
+    for p, want in ((params, 0), (nan_norm, 6)):
+        system = ServingSystem(p, cfg, n_prefill=1, decode_batch=2,
+                               capacity=32, decode_chunk=chunk)
+        results = system.serve(list(reqs))
+        assert [len(r.tokens) for r in results] == [6] * 3
+        assert [r.nonfinite_logits for r in results] == [want] * 3
 
 
 def test_serving_decode_chunk_with_reuse_and_trace(qwen):

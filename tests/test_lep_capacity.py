@@ -2,8 +2,7 @@
 
 These pin the *behaviour* of the static-buffer sizing — zero-token edge
 cases, capacity-factor rounding, sublane alignment, and the drop accounting
-of capacity-bounded dispatch — so the shard_map compat fix stays anchored to
-semantics rather than to imports alone.
+of capacity-bounded dispatch.
 """
 import jax.numpy as jnp
 import numpy as np
