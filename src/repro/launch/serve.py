@@ -38,7 +38,7 @@ from repro.core import init_mtp_params
 from repro.launch.compile_cache import CompileCounter, enable_compile_cache
 from repro.mempool import EMSService, MemoryPool
 from repro.models import init_params
-from repro.serving import Request, ServingSystem
+from repro.serving import Request, ServingSystem, obs
 from repro.serving.faults import FaultInjector, FaultPlan
 from repro.serving.pool import DECODE_ROUTERS
 from repro.serving.scheduler import ROUTERS
@@ -187,7 +187,9 @@ def build_parser() -> argparse.ArgumentParser:
                     help="arrival-time-driven serving on the virtual clock "
                          "(implied by --poisson-rate)")
     ap.add_argument("--trace", action="store_true",
-                    help="dump the structured per-request trace as JSON")
+                    help="dump the structured per-request trace (virtual "
+                         "clock) as JSON, and each measured span's count, "
+                         "total and mean host time")
     ap.add_argument("--fault-plan", default=None,
                     help="deterministic fault schedule: 'random' (seeded by "
                          "--fault-seed), '@path/to/plan.json', or inline "
@@ -328,9 +330,11 @@ def main() -> None:
     compiles = CompileCounter()
     dep = build(args)
     cfg, system, cc, injector = dep.cfg, dep.system, dep.cache, dep.injector
+    obs.enable(args.trace)
     t0 = time.time()
     results = system.serve(dep.requests, open_loop=dep.open_loop)
     dt = time.time() - t0
+    obs.enable(False)
     total_new = sum(len(r.tokens) for r in results if not r.shed)
     for r in sorted(results, key=lambda r: r.rid):
         flag = " SHED" if r.shed else ""
@@ -429,6 +433,9 @@ def main() -> None:
               f"corruptions={xfer.corruptions}")
     if args.trace:
         print(json.dumps(system.scheduler.trace_records(), indent=1))
+        print("spans (host clock): name count total_ms mean_ms")
+        for name, (n, total) in obs.summary(obs.snapshot()).items():
+            print(f"  {name} {n} {total * 1e3:.3f} {total * 1e3 / n:.3f}")
 
 
 if __name__ == "__main__":

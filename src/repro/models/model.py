@@ -173,6 +173,12 @@ def unembed(params: dict, cfg: ModelConfig, x: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+# Each block runs under a named scope ("attention", "mlp", "moe"): the
+# scope lands in the ops' metadata only, so a device trace can group the
+# operations of one layer part; the computation is unchanged.
+
+
+@jax.named_scope("attention")
 def _attn_block_prefill(pl_attn, x, cfg, positions):
     h = rms_norm(x, pl_attn["ln"], cfg.norm_eps)
     if cfg.attention_kind == "mla":
@@ -193,6 +199,7 @@ def _attn_block_prefill(pl_attn, x, cfg, positions):
     return x + out, (k, v)
 
 
+@jax.named_scope("attention")
 def _attn_block_decode(pl_attn, x, cfg, cache_k, cache_v, cache_len, ring):
     h = rms_norm(x, pl_attn["ln"], cfg.norm_eps)
     if cfg.attention_kind == "mla":
@@ -203,11 +210,13 @@ def _attn_block_decode(pl_attn, x, cfg, cache_k, cache_v, cache_len, ring):
     return x + out, ck, cv
 
 
+@jax.named_scope("mlp")
 def _mlp_block(pl_mlp, x, cfg):
     h = rms_norm(x, pl_mlp["ln"], cfg.norm_eps)
     return x + swiglu(h, pl_mlp["w_gate"], pl_mlp["w_up"], pl_mlp["w_down"])
 
 
+@jax.named_scope("moe")
 def _moe_block(pl_moe, x, cfg, moe_fn: MoeFn):
     b, s, d = x.shape
     h = rms_norm(x, pl_moe["ln"], cfg.norm_eps)
@@ -358,8 +367,10 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
             if cfg.attention_kind == "mla":
                 def body(h, xs):
                     pl, c = xs
-                    hin = rms_norm(h, pl["attn"]["ln"], cfg.norm_eps)
-                    out, nc = mla_mod.mla_decode(pl["attn"], hin, c, cache_len, cfg)
+                    with jax.named_scope("attention"):
+                        hin = rms_norm(h, pl["attn"]["ln"], cfg.norm_eps)
+                        out, nc = mla_mod.mla_decode(pl["attn"], hin, c,
+                                                     cache_len, cfg)
                     h2 = h + out
                     if seg.kind == "moe":
                         h2, _ = _moe_block(pl["moe"], h2, cfg, moe_fn)
@@ -767,8 +778,10 @@ def prefill_continue(params: dict, cfg: ModelConfig, tokens: jax.Array,
         if cfg.attention_kind == "mla":
             def body(h, xs, seg=seg):
                 pl, c = xs
-                hin = rms_norm(h, pl["attn"]["ln"], cfg.norm_eps)
-                out, nc = mla_mod.mla_extend(pl["attn"], hin, c, offset, cfg)
+                with jax.named_scope("attention"):
+                    hin = rms_norm(h, pl["attn"]["ln"], cfg.norm_eps)
+                    out, nc = mla_mod.mla_extend(pl["attn"], hin, c, offset,
+                                                 cfg)
                 h = h + out
                 if seg.kind == "moe":
                     h, _ = _moe_block(pl["moe"], h, cfg, moe_fn)
@@ -781,9 +794,10 @@ def prefill_continue(params: dict, cfg: ModelConfig, tokens: jax.Array,
         else:
             def body(h, xs, seg=seg):
                 pl, ck, cv = xs
-                hin = rms_norm(h, pl["attn"]["ln"], cfg.norm_eps)
-                out, nk, nv = attn_mod.attention_extend(pl["attn"], hin, ck,
-                                                        cv, offset, cfg)
+                with jax.named_scope("attention"):
+                    hin = rms_norm(h, pl["attn"]["ln"], cfg.norm_eps)
+                    out, nk, nv = attn_mod.attention_extend(pl["attn"], hin,
+                                                            ck, cv, offset, cfg)
                 h = h + out
                 if seg.kind == "moe":
                     h, _ = _moe_block(pl["moe"], h, cfg, moe_fn)

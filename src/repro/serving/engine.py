@@ -35,7 +35,7 @@ from repro.core import mtp as mtp_mod
 from repro.mempool.context_cache import ContextCache
 from repro.mempool.ems import EMSService
 from repro.models import model as model_mod
-from repro.serving import cache_ops
+from repro.serving import cache_ops, obs
 from repro.serving.faults import FaultInjector
 from repro.serving.pool import (DecodePool, DrainError, JointAutoscaler,
                                 PoolAutoscaler, PrefillPool,
@@ -110,21 +110,26 @@ class PrefillEngine:
         self.continue_calls = 0            # fresh-path dispatches
         self.continue_widths: set = set()  # fresh-path compiled widths
         self.suffix_calls = 0              # EMS-suffix dispatches
-        self.suffix_widths: set = set()
         self._chunkable = model_mod.supports_prefill_continue(cfg, capacity)
-        self._prefill = jax.jit(
-            lambda p, b: model_mod.prefill(p, cfg, b, capacity, moe_fn,
-                                           cache_dtype=jnp.float32))
+
+        # Named functions, so that a device trace names the serving
+        # program that ran (``jit_pdc_prefill_continue``).
+        def pdc_prefill(p, b):
+            return model_mod.prefill(p, cfg, b, capacity, moe_fn,
+                                     cache_dtype=jnp.float32)
+
+        def pdc_prefill_token(p, t, c, l):
+            return model_mod.decode_step(p, cfg, t, c, l, moe_fn)
+
+        def pdc_prefill_continue(p, t, c, off):
+            return model_mod.prefill_continue(p, cfg, t, c, off, moe_fn)
+
+        self._prefill = jax.jit(pdc_prefill)
         # Per-token fallback for archs prefill_continue cannot serve
         # (ring-buffer caches). Cache buffers are donated: the suffix loop
         # updates them in place instead of copying per step.
-        self._step = jax.jit(
-            lambda p, t, c, l: model_mod.decode_step(p, cfg, t, c, l, moe_fn),
-            donate_argnums=(2,))
-        self._continue = jax.jit(
-            lambda p, t, c, off: model_mod.prefill_continue(p, cfg, t, c,
-                                                            off, moe_fn),
-            donate_argnums=(2,))
+        self._step = jax.jit(pdc_prefill_token, donate_argnums=(2,))
+        self._continue = jax.jit(pdc_prefill_continue, donate_argnums=(2,))
 
     def _fresh_cache(self):
         return model_mod.make_caches(self.cfg, 1, self.capacity, jnp.float32)
@@ -161,7 +166,6 @@ class PrefillEngine:
                 self.continue_widths.add(width)
             else:
                 self.suffix_calls += 1
-                self.suffix_widths.add(width)
             logits, caches = self._continue(self.params, toks, caches,
                                             jnp.int32(pos))
             pos += len(part)
@@ -171,9 +175,11 @@ class PrefillEngine:
 
     def run(self, req: Request) -> Tuple[int, Any, RequestResult]:
         """Process one prompt. Returns (first_token, caches(B=1), result)."""
-        last, caches, res = self.run_logits(req)
-        first, finite = jax.device_get((jnp.argmax(last),
-                                        jnp.isfinite(last).all()))
+        with obs.span("prefill", req.rid):
+            last, caches, res = self.run_logits(req)
+            with obs.span("prefill.first_token", req.rid):
+                first, finite = jax.device_get((jnp.argmax(last),
+                                                jnp.isfinite(last).all()))
         res.nonfinite_logits += int(not finite)
         return int(first), caches, res
 
@@ -189,20 +195,22 @@ class PrefillEngine:
             caches = None
             if self.cc is not None and cfg.attention_kind != "none" \
                     and not cfg.is_hybrid:
-                reuse_len, keys = self.cc.match_prefix(prompt)
-                reuse_len = min(reuse_len, len(prompt) - 1)
-                reuse_len -= reuse_len % self.cc.block
-                keys = keys[: reuse_len // self.cc.block]
-                if reuse_len > 0:
+                with obs.span("prefill.ems_fetch", req.rid):
+                    reuse_len, keys = self.cc.match_prefix(prompt)
+                    reuse_len = min(reuse_len, len(prompt) - 1)
+                    reuse_len -= reuse_len % self.cc.block
+                    keys = keys[: reuse_len // self.cc.block]
                     # Resolve through the cache service (EMS: engine-HBM
                     # tier first, then pooled tier with an RDMA promote). A
                     # block evicted between match and fetch shortens the
                     # returned prefix — shrink the reuse and recompute the
                     # rest instead of crashing on the race.
-                    flats = self.cc.fetch(keys, engine=self._ems_tag)
-                    if len(flats) < len(keys):
-                        reuse_len = len(flats) * self.cc.block
-                    if reuse_len > 0:
+                    flats = (self.cc.fetch(keys, engine=self._ems_tag)
+                             if keys else [])
+                if len(flats) < len(keys):
+                    reuse_len = len(flats) * self.cc.block
+                if reuse_len > 0:
+                    with obs.span("prefill.ems_insert", req.rid):
                         caches = self._fresh_cache()
                         tmpl = cache_ops.seq_slice(cfg, caches, 0,
                                                    self.cc.block)
@@ -210,40 +218,9 @@ class PrefillEngine:
                             payload = cache_ops.unpack_payload(flat, tmpl)
                             caches = cache_ops.seq_insert(
                                 cfg, caches, payload, bi * self.cc.block)
-            if reuse_len > 0:
-                # Suffix-only computation: teacher-forced continuation from
-                # the reused prefix (positions offset by reuse_len). The
-                # whole suffix runs in chunked prefill_continue calls — one
-                # jitted dispatch per SUFFIX_CHUNK tokens instead of one per
-                # token (ring-buffer caches fall back to the token loop).
-                if not self._chunkable:
-                    logits = None
-                    cl = jnp.int32(reuse_len)
-                    for tok in prompt[reuse_len:]:
-                        t = jnp.full((1, 1), tok, jnp.int32)
-                        logits, caches = self._step(self.params, t, caches, cl)
-                        cl = cl + 1
-                    last = logits[0]
-                else:
-                    last, caches, _ = self._continue_chunks(
-                        prompt[reuse_len:], caches, reuse_len,
-                        self.suffix_chunk, fresh=False)
-                res.computed_tokens = len(prompt) - reuse_len
-            elif self.prefill_chunk and self._chunkable:
-                # Fresh prompt, bounded compile shapes: the whole prompt
-                # runs through chunked prefill_continue calls against a
-                # fresh cache (offset 0) — one compiled program per chunk
-                # width instead of one per prompt length, so long/varied
-                # prompts stop exploding the jit cache.
-                caches = self._fresh_cache()
-                last, caches, _ = self._continue_chunks(
-                    prompt, caches, 0, self.prefill_chunk, fresh=True)
-                res.computed_tokens = len(prompt)
-            else:
-                batch = {"tokens": jnp.asarray([prompt], jnp.int32)}
-                logits, caches = self._prefill(self.params, batch)
-                last = logits[0, len(prompt) - 1]
-                res.computed_tokens = len(prompt)
+            with obs.span("prefill.compute", req.rid):
+                last, caches = self._compute(prompt, caches, reuse_len)
+            res.computed_tokens = len(prompt) - reuse_len
             res.reused_tokens = reuse_len
 
             # Store newly computed full blocks back to EMS (async IRL).
@@ -251,14 +228,52 @@ class PrefillEngine:
             if self.cc is not None and cfg.attention_kind != "none" \
                     and not cfg.is_hybrid:
                 n_blocks = len(prompt) // self.cc.block
-                payloads = cache_ops.pack_blocks(cfg, caches, n_blocks,
-                                                 self.cc.block)
+                with obs.span("prefill.ems_pack", req.rid):
+                    payloads = cache_ops.pack_blocks(cfg, caches, n_blocks,
+                                                     self.cc.block)
                 if payloads:
-                    self.cc.store(prompt[: n_blocks * self.cc.block],
-                                  payloads, engine=self._ems_tag)
+                    with obs.span("prefill.ems_store", req.rid):
+                        self.cc.store(prompt[: n_blocks * self.cc.block],
+                                      payloads, engine=self._ems_tag)
             return last, caches, res
         finally:
             self.load -= len(prompt)
+
+    def _compute(self, prompt: List[int], caches, reuse_len: int):
+        """Run the prompt's uncached tokens ``prompt[reuse_len:]`` through
+        the prefill programs; ``caches`` holds the reused prefix when
+        ``reuse_len`` > 0. Returns (last-position logits (V,), caches)."""
+        if reuse_len > 0:
+            # Suffix-only computation: teacher-forced continuation from the
+            # reused prefix (positions offset by reuse_len). The whole
+            # suffix runs in chunked prefill_continue calls — one jitted
+            # dispatch per SUFFIX_CHUNK tokens instead of one per token
+            # (ring-buffer caches fall back to the token loop).
+            if not self._chunkable:
+                logits = None
+                cl = jnp.int32(reuse_len)
+                for tok in prompt[reuse_len:]:
+                    t = jnp.full((1, 1), tok, jnp.int32)
+                    logits, caches = self._step(self.params, t, caches, cl)
+                    cl = cl + 1
+                return logits[0], caches
+            last, caches, _ = self._continue_chunks(
+                prompt[reuse_len:], caches, reuse_len, self.suffix_chunk,
+                fresh=False)
+            return last, caches
+        if self.prefill_chunk and self._chunkable:
+            # Fresh prompt, bounded compile shapes: the whole prompt runs
+            # through chunked prefill_continue calls against a fresh cache
+            # (offset 0) — one compiled program per chunk width instead of
+            # one per prompt length, so long/varied prompts stop exploding
+            # the jit cache.
+            last, caches, _ = self._continue_chunks(
+                prompt, self._fresh_cache(), 0, self.prefill_chunk,
+                fresh=True)
+            return last, caches
+        batch = {"tokens": jnp.asarray([prompt], jnp.int32)}
+        logits, caches = self._prefill(self.params, batch)
+        return logits[0, len(prompt) - 1], caches
 
 
 # ---------------------------------------------------------------------------
@@ -319,7 +334,7 @@ class DecodeEngine:
             warnings.warn("decode microbatch interleaving requested but "
                           f"disabled: {reason}", stacklevel=2)
 
-        def _step(p, t, c, l):
+        def pdc_decode_step(p, t, c, l):
             base = lambda tt, cc, ll: model_mod.decode_step(  # noqa: E731
                 p, cfg, tt, cc, ll, moe_fn)
             fn = interleaver.wrap(base, max_batch) if self.interleaved else base
@@ -327,7 +342,7 @@ class DecodeEngine:
 
         # Cache buffers are donated so each jitted step reuses them in
         # place instead of allocating + copying a fresh cache per token.
-        self._step = jax.jit(_step, donate_argnums=(2,))
+        self._step = jax.jit(pdc_decode_step, donate_argnums=(2,))
 
         # Continuous batching jits the scan at a small ladder of widths
         # (powers of two up to decode_chunk, plus decode_chunk itself) so
@@ -346,14 +361,14 @@ class DecodeEngine:
         self.dead_slot_iters = 0
 
         def _make_loop(width: int):
-            def _loop(p, t, c, l, left):
+            def pdc_decode_loop(p, t, c, l, left):
                 base = lambda tt, cc, ll: model_mod.decode_step(  # noqa: E731
                     p, cfg, tt, cc, ll, moe_fn)
                 fn = interleaver.wrap(base, max_batch) \
                     if self.interleaved else base
                 return model_mod.decode_loop(p, cfg, t, c, l, width,
                                              steps_left=left, step_fn=fn)
-            return jax.jit(_loop, donate_argnums=(2,))
+            return jax.jit(pdc_decode_loop, donate_argnums=(2,))
 
         self._make_loop = _make_loop
         if use_mtp:
@@ -487,12 +502,19 @@ class DecodeEngine:
         ``lv[i, j]`` false) feed the dead-slot counters without being
         charged as batch occupancy.
         """
-        if self.decode_chunk > 1:
-            width = (self._effective_chunk(refill_pending) if continuous
-                     else self.decode_chunk)
-            return (self._step_chunked_mtp(width) if self.use_mtp
-                    else self._step_chunked(width))
+        with obs.span("decode.chunk"):
+            if self.decode_chunk > 1:
+                width = (self._effective_chunk(refill_pending) if continuous
+                         else self.decode_chunk)
+                return (self._step_chunked_mtp(width) if self.use_mtp
+                        else self._step_chunked(width))
+            return self._step_once()
 
+    def _step_once(self) -> Tuple[List[RequestResult],
+                                  List[Tuple[List[int], List[int],
+                                             dict, List[int]]]]:
+        """One device iteration, one host sync: the path when
+        ``decode_chunk`` is 1."""
         self.iters += 1
         active_rids = [info.rid for _, info in self.slot_mgr.active_slots()]
         self.key, sub = jax.random.split(self.key)
@@ -553,16 +575,27 @@ class DecodeEngine:
         (finished earlier in the chunk, or capacity-frozen) burned a dead
         device iteration — logged in ``masked_rids``, never charged as
         live batch occupancy."""
-        left = np.zeros((self.b,), np.int32)
-        resident = {}                   # slot index -> rid at dispatch time
-        for i, info in self.slot_mgr.active_slots():
-            left[i] = min(info.payload.remaining, width)
-            resident[i] = info.rid
-        emitted, live, finite, self.cur_tok, self.caches, self.cache_len = \
-            self._get_loop(width)(self.params, self.cur_tok, self.caches,
-                                  self.cache_len, jnp.asarray(left))
-        em, lv, fin = jax.device_get((emitted, live, finite))
+        with obs.span("decode.dispatch"):
+            left = np.zeros((self.b,), np.int32)
+            resident = {}               # slot index -> rid at dispatch time
+            for i, info in self.slot_mgr.active_slots():
+                left[i] = min(info.payload.remaining, width)
+                resident[i] = info.rid
+            emitted, live, finite, self.cur_tok, self.caches, \
+                self.cache_len = self._get_loop(width)(
+                    self.params, self.cur_tok, self.caches, self.cache_len,
+                    jnp.asarray(left))
+        with obs.span("decode.sync"):
+            em, lv, fin = jax.device_get((emitted, live, finite))
+        with obs.span("decode.commit"):
+            return self._commit_chunk(width, resident, em, lv, fin)
 
+    def _commit_chunk(self, width: int, resident: dict, em: np.ndarray,
+                      lv: np.ndarray, fin: np.ndarray) -> Tuple[
+            List[RequestResult],
+            List[Tuple[List[int], List[int], dict, List[int]]]]:
+        """The host's side of one scanned chunk: each live slot's tokens
+        appended iteration by iteration, finished slots released."""
         finished: List[RequestResult] = []
         iter_log: List[Tuple[List[int], List[int], dict, List[int]]] = []
         for j in range(width):
@@ -1257,12 +1290,19 @@ class ServingSystem:
         Poisson burst actually queues against the admission gate instead
         of being batched up front (closed loop, the default, feeds
         everything immediately)."""
+        with obs.span("serve.wave"):
+            return self._serve(requests, open_loop)
+
+    def _serve(self, requests: List[Request],
+               open_loop: bool) -> List[RequestResult]:
         sched = self.scheduler
         sched.begin_epoch()            # rids may repeat across serve() waves
         scaler = self._make_autoscaler()
         joint = self._make_joint()
         streaming = self._streamable()
         pending = sorted(requests, key=lambda r: (r.arrival, r.rid))
+        for req in pending:
+            obs.begin("queue.prefill", req.rid)
         results: List[RequestResult] = []
         waiting: List[_PendingAdmission] = []
         eps = 1e-12
@@ -1370,9 +1410,11 @@ class ServingSystem:
                         # requeue and retry after the next decode turn.
                         kept.extend(items[idx:])
                         return kept, True
-                    self.pool.add(engine, slot, item.caches, item.first,
-                                  item.prompt_len, item.result, item.max_new,
-                                  item.block_keys)
+                    obs.end("queue.decode", item.result.rid)
+                    with obs.span("handoff.insert", item.result.rid):
+                        self.pool.add(engine, slot, item.caches, item.first,
+                                      item.prompt_len, item.result,
+                                      item.max_new, item.block_keys)
                     if item.recovered:
                         sched.on_readmit(trace, engine, ready)
                     else:
@@ -1504,6 +1546,7 @@ class ServingSystem:
                 eng = self.prefills[sched.route_prefill(
                     trace, [e.load for e in self.prefills],
                     candidates=self.prefill_pool.live_ids)]
+                obs.end("queue.prefill", req.rid)
                 first, caches, res = eng.run(req)
                 res.slo_class = req.slo_class
                 sched.on_prefill_done(trace, eng.instance_id,
@@ -1518,19 +1561,22 @@ class ServingSystem:
                     sched.on_finish(trace, len(res.tokens))
                     results.append(res)
                     continue
-                if streaming:
-                    caches = self._stream_handoff(req, trace, res, caches)
-                else:
-                    res.transfer_seconds = self.transfer.transfer(
-                        caches, rid=req.rid)
-                    sched.on_transfer(trace, res.transfer_seconds)
+                with obs.span("handoff.transfer", req.rid):
+                    if streaming:
+                        caches = self._stream_handoff(req, trace, res, caches)
+                    else:
+                        res.transfer_seconds = self.transfer.transfer(
+                            caches, rid=req.rid)
+                        sched.on_transfer(trace, res.transfer_seconds)
                 keys = tuple(self.cc.block_keys(req.prompt)) if affinity \
                     else ()
                 self._inflight[req.rid] = req
                 waiting.append(_PendingAdmission(first, caches,
                                                  len(req.prompt), res,
                                                  req.max_new_tokens, keys))
-            admit_waiting()
+                obs.begin("queue.decode", req.rid)
+            with obs.span("serve.admit"):
+                admit_waiting()
             # Brownout ladder tick: one pressure observation per loop turn.
             # Pressure = a gate-ready interactive request is still blocked
             # after admission ran; calm turns (including idle ones) let the
@@ -1568,7 +1614,8 @@ class ServingSystem:
                         self._inflight.pop(r.rid, None)
                     results.extend(finished)
                     if continuous and waiting:
-                        admit_waiting(mid_turn=True)
+                        with obs.span("serve.admit"):
+                            admit_waiting(mid_turn=True)
                 sched.sync_idle_clocks(stepped)
                 if rebalance_every and decode_turns % rebalance_every == 0:
                     try:

@@ -36,8 +36,8 @@ directly buys TTFT), KV handoff is charged by the RDMA-plane
 :class:`~repro.serving.transfer.KVTransferEngine`, and each decode iteration
 costs ``t_fixed + B·t_per_req`` for the currently active batch ``B``. The
 timeline is deterministic given a request stream, which makes SLO behaviour
-assertable in tests; on real hardware the same trace schema is stamped from
-measured timestamps.
+assertable in tests. :class:`RequestTrace` is that virtual clock only; the
+measured host-clock spans of the serve path live in ``serving/obs.py``.
 """
 from __future__ import annotations
 
