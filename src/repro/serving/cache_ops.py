@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import functools
 import zlib
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -117,6 +117,32 @@ def pack_blocks(cfg: ModelConfig, caches, n_blocks: int,
         return []
     flat = np.asarray(_pack_blocks(cfg, caches, n_blocks, block))
     return [flat[bi] for bi in range(n_blocks)]
+
+
+@functools.partial(jax.jit, static_argnums=(0, 3), donate_argnums=(1,))
+def insert_blocks(cfg: ModelConfig, caches, rows: Sequence[jax.Array],
+                  block: int):
+    """Inverse of :func:`pack_blocks`: write ``rows``, row ``bi`` the EMS
+    payload of tokens [bi*block, (bi+1)*block), into ``caches`` at token
+    offset 0 with one update per leaf. The rows come as separate arrays
+    and are stacked here, on the device: on the host, stacking a long
+    prefix costs more than its transfer. ``caches`` is donated, so the
+    write lands in place."""
+    n_blocks = len(rows)
+    blocks = jnp.stack(rows)
+    template = jax.eval_shape(
+        lambda c: seq_slice(cfg, c, 0, n_blocks * block), caches)
+    leaves, treedef = jax.tree.flatten(template)
+    out, off = [], 0
+    for leaf in leaves:
+        # leaf: (L, B, n_blocks*block, ...); its columns of a row ravel
+        # (L, B, block, ...), as _pack_blocks laid them out.
+        width = leaf.size // n_blocks
+        x = blocks[:, off:off + width].reshape(
+            (n_blocks,) + leaf.shape[:2] + (block,) + leaf.shape[3:])
+        out.append(jnp.moveaxis(x, 0, 2).reshape(leaf.shape))
+        off += width
+    return seq_insert(cfg, caches, jax.tree.unflatten(treedef, out), 0)
 
 
 def payload_token_nbytes(cfg: ModelConfig, caches) -> int:

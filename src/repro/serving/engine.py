@@ -210,14 +210,13 @@ class PrefillEngine:
                 if len(flats) < len(keys):
                     reuse_len = len(flats) * self.cc.block
                 if reuse_len > 0:
+                    # Every fetched block in one batched host->device
+                    # transfer, written into the fresh cache by one jitted
+                    # program.
                     with obs.span("prefill.ems_insert", req.rid):
-                        caches = self._fresh_cache()
-                        tmpl = cache_ops.seq_slice(cfg, caches, 0,
-                                                   self.cc.block)
-                        for bi, flat in enumerate(flats):
-                            payload = cache_ops.unpack_payload(flat, tmpl)
-                            caches = cache_ops.seq_insert(
-                                cfg, caches, payload, bi * self.cc.block)
+                        caches = cache_ops.insert_blocks(
+                            cfg, self._fresh_cache(), jax.device_put(flats),
+                            self.cc.block)
             with obs.span("prefill.compute", req.rid):
                 last, caches = self._compute(prompt, caches, reuse_len)
             res.computed_tokens = len(prompt) - reuse_len

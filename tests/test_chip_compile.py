@@ -24,6 +24,7 @@ import chip_smoke  # noqa: E402
 from repro.configs import get_config  # noqa: E402
 from repro.launch import serve  # noqa: E402
 from repro.models import model as model_mod  # noqa: E402
+from repro.serving import cache_ops  # noqa: E402
 from repro.serving.engine import PrefillEngine  # noqa: E402
 
 #: HBM one v5e chip lets a program use (the figure the compiler's own
@@ -91,7 +92,7 @@ def test_kernel_compiles_for_v5e(one_chip, name):
 
 
 @pytest.mark.parametrize("program", ["decode_step", "decode_loop", "prefill",
-                                     "prefill_continue"])
+                                     "prefill_continue", "ems_insert"])
 def test_granite_serve_programs_fit_one_v5e(one_chip, program):
     """Each full-width program chip_smoke's deployment compiles, at its
     batch and capacity, with caches donated as the engines donate them,
@@ -123,6 +124,16 @@ def test_granite_serve_programs_fit_one_v5e(one_chip, program):
             p, cfg, {"tokens": t}, cap, cache_dtype=jnp.float32))
         lowered = fn.lower(params, _spec((1, args.prompt_len), jnp.int32,
                                          one_chip))
+    elif program == "ems_insert":
+        # The shared prefix's EMS blocks, 8 tokens each as serve.build's
+        # EMSService holds them, into a fresh prefill cache.
+        block = 8
+        n_blocks = args.shared_prefix // block
+        row = sum(a.size for a in jax.tree.leaves(jax.eval_shape(
+            lambda c: cache_ops.seq_slice(cfg, c, 0, block), caches(1))))
+        lowered = cache_ops.insert_blocks.lower(
+            cfg, caches(1), [_spec((row,), jnp.float32, one_chip)] * n_blocks,
+            block)
     else:
         width = PrefillEngine.SUFFIX_CHUNK
         fn = jax.jit(lambda p, t, c, off: model_mod.prefill_continue(

@@ -12,8 +12,8 @@ import numpy as np
 import pytest
 
 from conftest import smoke
-from repro.models import (decode_loop, decode_step, init_params, prefill,
-                          prefill_continue)
+from repro.models import (decode_loop, decode_step, init_params, make_caches,
+                          prefill, prefill_continue)
 from repro.serving import (DecodeCostModel, MicrobatchInterleaver, Request,
                            SchedulerConfig, ServingSystem,
                            decode_cost_from_roofline)
@@ -195,6 +195,101 @@ def test_pack_blocks_matches_per_block_pack(arch):
             cache_ops.seq_slice(cfg, caches, bi * block, block))
         assert np.array_equal(rows[bi], ref), f"block {bi} differs"
     assert cache_ops.pack_blocks(cfg, caches, 0, block) == []
+
+
+def _insert_per_block(cfg, caches, rows, block):
+    """EMS rows into ``caches`` one block at a time: an unpack_payload and a
+    seq_insert each."""
+    tmpl = cache_ops.seq_slice(cfg, caches, 0, block)
+    for bi, row in enumerate(rows):
+        caches = cache_ops.seq_insert(
+            cfg, caches, cache_ops.unpack_payload(row, tmpl), bi * block)
+    return caches
+
+
+@pytest.mark.parametrize("arch", ["qwen3-8b", "deepseek-r1", "olmoe-1b-7b"])
+def test_insert_blocks_matches_per_block_insert(arch):
+    """insert_blocks of pack_blocks rows into a fresh cache equals the
+    per-block unpack_payload/seq_insert loop bit for bit, over the blocks
+    and beyond them, and holds the source cache's tokens over the blocks."""
+    cfg = smoke(arch)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    capacity = 24
+    _, _, caches, _ = _prefill_batch(cfg, params, n_req=1, plen=16,
+                                     capacity=capacity)
+    block, n_blocks = 4, 3
+    rows = cache_ops.pack_blocks(cfg, caches, n_blocks, block)
+
+    def fresh():
+        return make_caches(cfg, 1, capacity, jnp.float32)
+
+    want = _insert_per_block(cfg, fresh(), rows, block)
+    got = cache_ops.insert_blocks(cfg, fresh(), rows, block)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    assert _content_equal(got, want)
+    span = n_blocks * block
+    assert _content_equal(cache_ops.seq_slice(cfg, got, 0, span),
+                          cache_ops.seq_slice(cfg, caches, 0, span))
+    rest = capacity - span
+    assert not any(bool(jnp.any(x)) for x in jax.tree.leaves(
+        cache_ops.seq_slice(cfg, got, span, rest)))
+
+
+def test_prefill_engine_inserts_ems_hit_in_one_call(qwen, monkeypatch):
+    """An EMS hit of any block count lands through one insert_blocks call;
+    the first token and the last-position logits equal those computed from
+    a cache rebuilt block by block, and a second hit of the same block
+    count compiles nothing."""
+    from repro.mempool import ContextCache, MemoryPool
+    from repro.serving.engine import PrefillEngine
+
+    cfg, params = qwen
+    block = 4
+    cc = ContextCache(MemoryPool(n_nodes=4), block_tokens=block,
+                      model_tag=cfg.name)
+    engine = PrefillEngine(params, cfg, 48, context_cache=cc)
+    calls = []
+    insert = cache_ops.insert_blocks
+
+    def counted(*args):
+        calls.append(len(args[2]))
+        return insert(*args)
+
+    monkeypatch.setattr(cache_ops, "insert_blocks", counted)
+    compiles = []
+
+    def on_event(event, duration_secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration_secs)
+
+    def per_block_logits(prompt, n_blocks):
+        _, keys = cc.match_prefix(prompt)
+        rows = cc.fetch(keys[:n_blocks])
+        caches = _insert_per_block(cfg, engine._fresh_cache(), rows, block)
+        last, _ = engine._compute(prompt, caches, n_blocks * block)
+        return np.asarray(last)
+
+    rng = np.random.RandomState(11)
+    for n_blocks in (1, 3, 6):
+        doc = list(rng.randint(0, 200, n_blocks * block))
+        engine.run(Request(-n_blocks, doc, 1))        # stores, no hit
+        prompt = doc + list(rng.randint(0, 200, 5))
+        first, _, res = engine.run(Request(n_blocks, prompt, 1))
+        assert res.reused_tokens == n_blocks * block
+        assert first == int(np.argmax(per_block_logits(prompt, n_blocks)))
+
+        prompt = doc + list(rng.randint(0, 200, 5))
+        jax.monitoring.register_event_duration_secs_listener(on_event)
+        try:
+            last, _, res = engine.run_logits(Request(100 + n_blocks, prompt,
+                                                     1))
+            last = np.asarray(last)
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on_event)
+        assert res.reused_tokens == n_blocks * block
+        assert compiles == [], f"{len(compiles)} compiles at {n_blocks} blocks"
+        assert np.array_equal(last, per_block_logits(prompt, n_blocks))
+    assert calls == [1, 1, 3, 3, 6, 6]
 
 
 # ---------------------------------------------------------------------------
