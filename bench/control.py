@@ -7,7 +7,8 @@ For each seed, in one process: a run of the cell as ``bench/run.py`` makes
 it (short window, same load), then the widest gap of the served tokens below
 the float32 reference's best (the program's reading) and the widest gap of
 the tokens that the fp8 control would have picked at the same positions
-(the control's reading). One JSON line per seed on stdout. The benchmark's
+(the control's reading). Both come from the reference module that the
+cell's configuration names (``bench/harness.py``, ``load_part``). One JSON line per seed on stdout. The benchmark's
 own runs never run the control.
 """
 import argparse

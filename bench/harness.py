@@ -9,6 +9,14 @@ metric is a file of its own, found by the name ``BENCHMARK.json`` gives it:
 * ``bench/metrics/<metric>.py``   a reader, ``read(run) -> float | None``;
 * ``bench/limits/<workload>.json`` the limit of each number compared.
 
+A configuration file names the parts that depend on its architecture, so
+that a new architecture arrives as new files only: ``program.fields``
+(``ModelConfig`` fields set verbatim after the key mapping of
+:func:`program_config`, for sizes that no key of the file states),
+``reference`` and ``counts`` (modules, as paths inside the checkout from
+its root; ``PARTS`` gives the defaults) and ``weights``
+(rules for leaves the default draws do not cover, ``bench/weights.py``).
+
 Times are host clock (``time.perf_counter``) around calls that end on the
 device: ``PrefillEngine.run`` ends in a ``device_get`` of the first token,
 ``DecodeEngine.step_chunk`` in one of the emitted tokens. A token is stamped
@@ -21,6 +29,7 @@ import gc
 import importlib.util
 import json
 import os
+import re
 import sys
 import time
 from typing import Any, Callable, Dict, List, Optional
@@ -33,6 +42,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHECK_REQUESTS = 4
 #: span kinds of the benchmark's host spans (and trace annotations)
 SPANS = ("prefill", "handoff", "decode")
+#: modules a configuration file may name, and the one each defaults to
+PARTS = {"reference": "bench/reference.py", "counts": "bench/counts.py"}
 
 
 # ---------------------------------------------------------------------------
@@ -53,6 +64,18 @@ def load_module(path: str, name: str):
     sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_part(conf: dict, part: str, root: str = ROOT):
+    """The module that the configuration names for ``part``, a path from
+    the checkout's root, or the default of ``PARTS``."""
+    rel = conf.get(part, PARTS[part])
+    top = os.path.realpath(root)
+    path = os.path.realpath(os.path.join(top, rel))
+    if os.path.commonpath([top, path]) != top:
+        raise ValueError(f"{conf['name']}: {part} {rel!r} lies outside the "
+                         f"checkout")
+    return load_module(path, f"bench_{part}_" + re.sub(r"\W", "_", rel))
 
 
 @dataclasses.dataclass
@@ -101,24 +124,62 @@ def find_cell(name: str, root: str = ROOT) -> Cell:
 # ---------------------------------------------------------------------------
 
 
+#: DeepSeek's keys that set a ModelConfig field of their own, where stated
+DEEPSEEK_KEYS = {"n_routed_experts": "num_experts",
+                 "n_shared_experts": "num_shared_experts",
+                 "first_k_dense_replace": "first_k_dense",
+                 "q_lora_rank": "q_lora_rank", "kv_lora_rank": "kv_lora_rank",
+                 "qk_nope_head_dim": "qk_nope_head_dim",
+                 "qk_rope_head_dim": "qk_rope_head_dim",
+                 "v_head_dim": "v_head_dim"}
+
+
 def program_config(conf: dict):
     """The program's registered config of the file's architecture, with
-    every size the file states put in: the file is what is run."""
+    every size the file states put in: the file is what is run. The keys
+    below and ``DEEPSEEK_KEYS`` are mapped first; then ``program.fields``
+    sets ``ModelConfig`` fields verbatim, for sizes that no key of the file
+    states. A field that a stated key sets is refused: change the key."""
     from repro.configs import get_config
     d, h = conf["hidden_size"], conf["num_attention_heads"]
     base = get_config(conf["program"]["arch"])
-    return dataclasses.replace(
-        base, name=conf["name"], num_layers=conf["num_hidden_layers"],
-        d_model=d, d_ff=conf["intermediate_size"], num_heads=h,
-        num_kv_heads=conf["num_key_value_heads"],
-        head_dim=conf.get("head_dim") or d // h,
-        vocab_size=conf["vocab_size"], rope_theta=conf["rope_theta"],
-        norm_eps=conf["rms_norm_eps"],
-        tie_embeddings=conf["tie_word_embeddings"],
-        num_experts=conf.get("num_experts", 0),
-        num_experts_per_tok=conf.get("num_experts_per_tok", 0),
-        qk_norm=conf.get("qk_norm") == "per_head", dtype=conf["torch_dtype"],
-        capacity_factor=conf.get("capacity_factor", base.capacity_factor))
+    # field: (the file's key that states it, the value the program takes)
+    mapped = {
+        "num_layers": ("num_hidden_layers", conf["num_hidden_layers"]),
+        "d_model": ("hidden_size", d),
+        "d_ff": ("intermediate_size", conf["intermediate_size"]),
+        "num_heads": ("num_attention_heads", h),
+        "num_kv_heads": ("num_key_value_heads",
+                         conf["num_key_value_heads"]),
+        "head_dim": ("head_dim", conf.get("head_dim") or d // h),
+        "vocab_size": ("vocab_size", conf["vocab_size"]),
+        "rope_theta": ("rope_theta", conf["rope_theta"]),
+        "norm_eps": ("rms_norm_eps", conf["rms_norm_eps"]),
+        "tie_embeddings": ("tie_word_embeddings",
+                           conf["tie_word_embeddings"]),
+        "num_experts": ("num_experts", conf.get("num_experts", 0)),
+        "num_experts_per_tok": ("num_experts_per_tok",
+                                conf.get("num_experts_per_tok", 0)),
+        "qk_norm": ("qk_norm", conf.get("qk_norm") == "per_head"),
+        "dtype": ("torch_dtype", conf["torch_dtype"]),
+        "capacity_factor": ("capacity_factor", conf.get(
+            "capacity_factor", base.capacity_factor)),
+    }
+    mapped.update({f: (k, conf[k]) for k, f in DEEPSEEK_KEYS.items()
+                   if k in conf})
+    cfg = dataclasses.replace(base, name=conf["name"],
+                              **{f: v for f, (_, v) in mapped.items()})
+    fields = conf["program"].get("fields", {})
+    known = {f.name for f in dataclasses.fields(cfg)}
+    for name in fields:
+        if name not in known:
+            raise ValueError(f"{conf['name']}: program.fields names "
+                             f"{name!r}, which ModelConfig lacks")
+        key = mapped.get(name, (None,))[0]
+        if key in conf:
+            raise ValueError(f"{conf['name']}: program.fields sets {name!r}, "
+                             f"which the file's key {key!r} states")
+    return dataclasses.replace(cfg, **fields)
 
 
 def param_shapes(cfg):
@@ -172,9 +233,8 @@ class Req:
 class Recorder:
     """Wraps the engine calls of one ServingSystem, from outside it."""
 
-    def __init__(self, conf: dict, peak: Optional[dict]):
-        from bench import counts
-        self.counts = counts
+    def __init__(self, conf: dict, peak: Optional[dict], root: str = ROOT):
+        self.counts = load_part(conf, "counts", root)
         self.conf = conf
         self.peak = peak
         self.reqs: Dict[int, Req] = {}
@@ -341,7 +401,6 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     ``after_build(system)`` may break the system under test (the tests of
     the check do); ``control`` also reads the fp8 control's gap."""
     import jax
-    from bench import reference as R
     from bench import weights as W
 
     conf = cell.config
@@ -349,7 +408,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     compiles = CompileCount()
     cfg = program_config(conf)
     shapes = param_shapes(cfg)
-    params = W.make_params(shapes, seed)
+    params = W.make_params(shapes, seed, conf.get("weights"))
     jax.block_until_ready(params)
     log(f"weights: {cfg.name}, {sum(a.size for a in jax.tree.leaves(params))} "
         f"parameters from seed {seed}, "
@@ -360,7 +419,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     system, ems = build_system(params, cfg, conf["deployment"], capacity)
     if after_build is not None:
         after_build(system)
-    rec = Recorder(conf, peak)
+    rec = Recorder(conf, peak, root)
     rec.attach(system)
     from repro.serving import Request
 
@@ -468,7 +527,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
               if res is not None and not res.shed and res.tokens}
     del system, ems, params
     gc.collect()
-    ref = R.Reference(conf, W.leaf_specs(shapes))
+    ref = load_part(conf, "reference", root).Reference(
+        conf, W.leaf_specs(shapes))
     sample = pick_sample(served, prompts, seed)
     gaps, control_gaps = compare(ref, seed, sample, prompts, served,
                                  capacity, control)

@@ -1,5 +1,9 @@
 """Plain reference of the decoder configurations, in float32, layer by layer.
 
+It is the default of a configuration file's ``reference`` key: a module
+named there gives ``Reference(conf, specs)`` with ``.logits(seed, tokens,
+rows, precision="float32"|"fp8")``, and may use the generic parts here
+(``check_rows``, ``served_gaps``, the fp8 rounding, the layer helpers).
 It imports nothing of the program. It reads a configuration file of
 ``bench/configs`` (Hugging Face key names, as run) and draws every weight
 again from the run's seed through :mod:`weights`, one layer at a time, so
@@ -170,6 +174,7 @@ class Reference:
 
     def __init__(self, conf: dict, specs: Sequence[Tuple[str, tuple, object]]):
         self.s = Shape(conf)
+        self.rules = conf.get("weights")
         self.specs = list(specs)
         prefix = f"segments/{self.s.segment}/"
         self.layer_leaves = [(path[len(prefix):], path, shape[1:], dtype)
@@ -184,14 +189,14 @@ class Reference:
         for name, path, shape, dtype in self.layer_leaves:
             group, leaf = name.split("/")
             out.setdefault(group, {})[leaf] = W.draw_layer(
-                key, path, layer, shape, dtype).astype(jnp.float32)
+                key, path, layer, shape, dtype, self.rules).astype(jnp.float32)
         return out
 
     @functools.partial(jax.jit, static_argnums=(0, 1))
     def _draw(self, path, key):
         shape, dtype = self.other[path]
-        return W.draw(W.leaf_key(key, path), path, shape,
-                      dtype).astype(jnp.float32)
+        return W.draw(W.leaf_key(key, path), path, shape, dtype,
+                      self.rules).astype(jnp.float32)
 
     @functools.partial(jax.jit, static_argnums=(0, 3))
     def _run_layer(self, p, x, fp8):

@@ -10,35 +10,52 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 from bench import counts, harness  # noqa: E402
 
-CONFIGS = ("granite-3-2b", "olmoe-1b-7b.half")
+BENCH = harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+FILES = {c["name"]: c["file"] for c in BENCH["configs"]}
+CONFIGS = tuple(FILES)
+#: an MLA + MoE configuration with counts of its own, kept as test data
+FILES["tiny-mla-moe"] = "bench/tests/data/mla_moe/tiny-mla-moe.json"
 
 
 def conf(name):
-    return harness.load_json(os.path.join(ROOT, "bench", "configs",
-                                          name + ".json"))
+    return harness.load_json(os.path.join(ROOT, FILES[name]))
 
 
-@pytest.mark.parametrize("name", CONFIGS)
+def counts_of(c):
+    """The configuration's own counts module."""
+    return harness.load_part(c, "counts")
+
+
+#: configurations counted by ``bench/counts.py`` (GQA), whose KV bytes the
+#: test below checks
+GQA = tuple(n for n in CONFIGS
+            if conf(n).get("counts", harness.PARTS["counts"])
+            == harness.PARTS["counts"])
+
+
+@pytest.mark.parametrize("name", CONFIGS + ("tiny-mla-moe",))
 def test_param_count_is_the_program_leaves(name):
     c = conf(name)
     shapes = harness.param_shapes(harness.program_config(c))
-    assert counts.param_count(c) == sum(a.size for a in jax.tree.leaves(shapes))
+    assert counts_of(c).param_count(c) \
+        == sum(a.size for a in jax.tree.leaves(shapes))
 
 
-@pytest.mark.parametrize("name", CONFIGS)
+@pytest.mark.parametrize("name", GQA)
 def test_decode_kv_bytes_at_the_configuration_dtype(name):
     """The program keeps its KV cache in float32; the roofline counts it at
     the configuration's bf16, 2 bytes a value."""
     c = conf(name)
+    own = counts_of(c)
     assert c["torch_dtype"] == "bfloat16"
     per_token = (c["num_hidden_layers"] * 2 * c["num_key_value_heads"]
                  * (c["hidden_size"] // c["num_attention_heads"]) * 2)
-    assert counts.kv_bytes_per_token(c) == per_token
-    _, b1 = counts.decode_iteration(c, [100, 200])
-    _, b2 = counts.decode_iteration(c, [101, 200])
+    assert own.kv_bytes_per_token(c) == per_token
+    _, b1 = own.decode_iteration(c, [100, 200])
+    _, b2 = own.decode_iteration(c, [101, 200])
     assert b2 - b1 == per_token
     f32 = dict(c, torch_dtype="float32")
-    assert counts.kv_bytes_per_token(f32) == 2 * per_token
+    assert own.kv_bytes_per_token(f32) == 2 * per_token
 
 
 def test_moe_decode_reads_only_reachable_experts():

@@ -7,6 +7,7 @@ import shutil
 import subprocess
 import sys
 import time
+import types
 
 import jax
 import pytest
@@ -75,6 +76,162 @@ def test_adding_a_cell_edits_no_file(tmp_path):
     assert "new_metric" not in old.readers
     for p, data in before.items():
         assert open(p, "rb").read() == data
+
+
+MLA_MOE = os.path.join("bench", "tests", "data", "mla_moe")
+PEAK = harness.load_json(os.path.join(ROOT, "bench", "peaks.json"))[
+    "TPU v5 lite"]
+#: the per-layer metrics that read a cell's own counts module
+COUNTED = ("decode_roofline", "decode_mfu", "mfu")
+
+
+@pytest.fixture(scope="module")
+def mla_moe(tmp_path_factory):
+    """A tiny MLA + MoE configuration (the program's ``deepseek-r1`` with
+    one dense and one MoE layer, one shared expert, softmax top-k) added to
+    a copy of ``bench/`` as new files only: its configuration file with
+    program ``fields`` and ``weights`` rules and its own reference and
+    counts modules (``bench/tests/data/mla_moe``, where the file names
+    them), a mix, a limit and entries in ``BENCHMARK.json``. One sound run,
+    whose Recorder is kept, and one with the tokens altered where they are
+    produced."""
+    root = tmp_path_factory.mktemp("mla_moe")
+    shutil.copytree(os.path.join(ROOT, "bench"), root / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    before = {p: open(p, "rb").read() for p in
+              map(str, (root / "bench").rglob("*")) if os.path.isfile(p)}
+    shutil.copytree(os.path.join(ROOT, MLA_MOE), root / MLA_MOE,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(root / MLA_MOE / "tiny-mla-moe.json",
+                root / "bench/configs/tiny-mla-moe.json")
+    (root / "bench/traffic/tiny.json").write_text(json.dumps(TINY_TRAFFIC))
+    (root / "bench/limits/tiny-mla-moe.tiny.json").write_text(
+        json.dumps({"max_logit_gap": {"limit": TINY_LIMIT}}))
+    bench = json.loads(json.dumps(BENCH))
+    bench["configs"].append({"name": "tiny-mla-moe", "source": "test",
+                             "file": "bench/configs/tiny-mla-moe.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "tiny-mla-moe.tiny",
+                               "config": "tiny-mla-moe", "traffic": "tiny",
+                               "chips": 1, "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    cell = harness.find_cell("tiny-mla-moe.tiny", root=str(root))
+    recorders = []
+
+    class Kept(harness.Recorder):
+        def __init__(self, *a, **kw):
+            super().__init__(*a, **kw)
+            recorders.append(self)
+
+    def run(after_build=None):
+        return harness.run_cell(cell, 2**31 + 77, 1.0, False,
+                                time.perf_counter(), jax.devices(),
+                                root=str(root), peak=PEAK,
+                                after_build=after_build)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(harness, "Recorder", Kept)
+        sound = run()
+    return {"root": root, "before": before, "cell": cell, "sound": sound,
+            "recorder": recorders[0], "altered": run(alter_tokens)}
+
+
+def test_new_architecture_arrives_as_new_files_only(mla_moe):
+    cell = mla_moe["cell"]
+    cfg = harness.program_config(cell.config)
+    assert (cfg.attention_kind, cfg.first_k_dense, cfg.num_experts,
+            cfg.num_shared_experts, cfg.head_dim, cfg.kv_lora_rank) \
+        == ("mla", 1, 8, 1, 48, 32)
+    counts = harness.load_part(cell.config, "counts", str(mla_moe["root"]))
+    shapes = harness.param_shapes(cfg)
+    assert counts.param_count(cell.config) \
+        == sum(a.size for a in jax.tree.leaves(shapes))
+    for p, data in mla_moe["before"].items():
+        assert open(p, "rb").read() == data
+
+
+def test_new_architecture_reads_the_model_step_through_its_counts(mla_moe):
+    """The model-step metrics join the new cell with no entry edited, and
+    read its run's decode calls and prefills through its own counts."""
+    cell, rec = mla_moe["cell"], mla_moe["recorder"]
+    assert set(COUNTED) <= set(cell.readers)
+    own = os.path.realpath(mla_moe["root"] / cell.config["counts"])
+    assert rec.counts.__file__ == own
+    from bench import counts as gqa
+    assert rec.counts.decode_iteration(cell.config, [100, 200]) \
+        != gqa.decode_iteration(cell.config, [100, 200])
+    calls = rec.decode_calls
+    t0, t1 = calls[0][0], calls[-1][1]
+    run = harness.Run(
+        conf=cell.config, peak=PEAK, rec=rec, reqs=list(rec.reqs.values()),
+        window=(t0, t1), seconds=t1 - t0, chips=1, counts=rec.counts,
+        decode_calls=calls, traced_calls=calls, traced=(t0, t1),
+        trace=types.SimpleNamespace(device_s_in={"decode": rec.busy_s(
+            t0, t1, ("decode",))}))
+    values = {m: cell.readers[m].read(run) for m in COUNTED}
+    assert all(v is not None and 0 < v <= 100 for v in values.values()), \
+        values
+    prefill = sum(rec.counts.prefill_flops(cell.config, r.reused,
+                                           r.prompt_len)
+                  for r in run.reqs if t0 <= r.first <= t1)
+    decode = sum(c[3] for c in calls)
+    assert values["mfu"] == pytest.approx(
+        100 * (prefill + decode) / (run.seconds * PEAK["bf16_flops_per_s"]))
+
+
+def test_new_architecture_run_is_correct(mla_moe):
+    out = mla_moe["sound"]
+    assert out["correct"], out["checks"]
+    assert out["failed"] == 0 and out["attempted"] > 0
+
+
+def test_new_architecture_fails_a_token_altered(mla_moe):
+    out = mla_moe["altered"]
+    assert not out["correct"]
+    assert out["checks"]["max_logit_gap"]["value"] > TINY_LIMIT
+
+
+def test_weight_rules_cover_leaves_by_name():
+    from bench import weights as W
+    key = W.base_key(3)
+    x = jax.random.normal(key, (16,))
+    path = "segments/moe/attn/q_ln"
+    with pytest.raises(ValueError, match=path):
+        W.draw(key, path, (16,), "float32")
+    assert (W.draw(key, path, (16,), "float32", {"q_ln": "gain"})
+            == 1.0 + 0.1 * x).all()
+    assert (W.draw(key, "segments/moe/moe/bias", (16,), "float32",
+                   {"bias": 0.5}) == 0.5 * x).all()
+    with pytest.raises(ValueError, match="gainz"):
+        W.draw(key, path, (16,), "float32", {"q_ln": "gainz"})
+
+
+def test_program_fields_name_a_missing_field():
+    conf = dict(TINY, program={"arch": "granite-3-2b",
+                               "fields": {"num_dragons": 3}})
+    with pytest.raises(ValueError, match="num_dragons"):
+        harness.program_config(conf)
+
+
+@pytest.mark.parametrize("field,key", [("num_layers", "num_hidden_layers"),
+                                       ("dtype", "torch_dtype"),
+                                       ("d_ff", "intermediate_size")])
+def test_program_fields_refuse_a_size_the_file_states(field, key):
+    """A field may not set what a key of the file states: the reference,
+    the counts and the audit of ``reduced`` read the key."""
+    conf = dict(TINY, program={"arch": "granite-3-2b",
+                               "fields": {field: TINY[key]}})
+    with pytest.raises(ValueError, match=key):
+        harness.program_config(conf)
+    head = dict(TINY, program={"arch": "granite-3-2b",
+                               "fields": {"head_dim": 48}})
+    assert harness.program_config(head).head_dim == 48
+
+
+@pytest.mark.parametrize("rel", ["/etc/counts.py", "bench/../../counts.py"])
+def test_parts_lie_inside_the_checkout(rel):
+    with pytest.raises(ValueError, match="outside the checkout"):
+        harness.load_part(dict(TINY, counts=rel), "counts")
 
 
 def _run_cli(cwd, *extra):
