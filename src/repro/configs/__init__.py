@@ -1,6 +1,7 @@
 """Architecture configs. Importing this package registers every config."""
 from repro.configs.base import (  # noqa: F401
     INPUT_SHAPES,
+    DeepSeekV3Config,
     InputShape,
     ModelConfig,
     get_config,
@@ -21,6 +22,7 @@ from repro.configs import internvl2_2b  # noqa: F401
 from repro.configs import phi3_medium_14b  # noqa: F401
 from repro.configs import granite_3_2b  # noqa: F401
 from repro.configs import deepseek_r1  # noqa: F401
+from repro.configs import deepseek_v3  # noqa: F401
 
 ASSIGNED_ARCHS = [
     "qwen3-8b",

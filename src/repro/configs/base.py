@@ -26,7 +26,7 @@ class ModelConfig:
     num_heads: int                   # 0 => attention-free
     num_kv_heads: int
     head_dim: int
-    d_ff: int                        # dense FFN width (per-expert width for MoE)
+    d_ff: int                        # dense FFN width (and the experts' where moe_d_ff is 0)
     vocab_size: int
 
     # --- attention options -------------------------------------------------
@@ -46,7 +46,7 @@ class ModelConfig:
     v_head_dim: int = 0
 
     # --- MoE ----------------------------------------------------------------
-    num_experts: int = 0
+    num_experts: int = 0             # experts held (and computed) here
     num_experts_per_tok: int = 0
     num_shared_experts: int = 0
     first_k_dense: int = 0           # leading dense layers in MoE models
@@ -73,10 +73,49 @@ class ModelConfig:
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
 
+    # --- DeepSeek-V3's mechanisms (fields of DeepSeekV3Config) -------------
+    # Plain class attributes here, not fields: every other config reads
+    # them as the behaviour it always had, and its fields (what it compares,
+    # hashes and prints as) stay as they were.
+    moe_d_ff = 0                     # routed and shared experts' width; 0 => d_ff
+    router_experts = 0               # router's width; 0 => num_experts
+    first_held_expert = 0
+    router_score = "softmax"         # softmax | sigmoid
+    n_group = 1
+    topk_group = 1
+    routed_scaling = 1.0
+    yarn_factor = 1.0                # 1.0 => plain rope
+    yarn_original_max = 4096
+    yarn_beta_fast = 32.0
+    yarn_beta_slow = 1.0
+    yarn_mscale = 1.0
+    yarn_mscale_all_dim = 1.0
+
     # ------------------------------------------------------------------
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def router_width(self) -> int:
+        return self.router_experts or self.num_experts
+
+    @property
+    def holds_every_expert(self) -> bool:
+        return self.router_width == self.num_experts and self.first_held_expert == 0
+
+    @property
+    def yarn(self) -> Optional[tuple]:
+        """YaRN's (factor, original max positions, beta_fast, beta_slow,
+        mscale, mscale_all_dim), or None for plain rope."""
+        if self.yarn_factor <= 1.0:
+            return None
+        return (self.yarn_factor, self.yarn_original_max, self.yarn_beta_fast,
+                self.yarn_beta_slow, self.yarn_mscale, self.yarn_mscale_all_dim)
 
     @property
     def is_ssm(self) -> bool:
@@ -110,7 +149,7 @@ class ModelConfig:
     def param_count(self, active_only: bool = False) -> int:
         d, v = self.d_model, self.vocab_size
         emb = v * d * (1 if self.tie_embeddings else 2)
-        total = emb
+        total = emb + d  # + the final norm
         for li in range(self.num_layers):
             total += self._layer_params(li, active_only)
         return total
@@ -136,6 +175,7 @@ class ModelConfig:
             p += d * (self.kv_lora_rank + self.qk_rope_head_dim)
             p += self.kv_lora_rank * self.num_heads * (self.qk_nope_head_dim + self.v_head_dim)
             p += self.num_heads * self.v_head_dim * d
+            p += self.q_lora_rank + self.kv_lora_rank  # latent norms
         elif self.num_heads > 0:
             q = d * self.num_heads * self.head_dim
             kv = 2 * d * self.num_kv_heads * self.head_dim
@@ -144,11 +184,44 @@ class ModelConfig:
         # FFN
         if self.is_moe and layer_idx >= self.first_k_dense:
             e_active = self.num_experts_per_tok if active_only else self.num_experts
-            p += (e_active + self.num_shared_experts) * 3 * d * self.d_ff
-            p += d * self.num_experts  # router
+            p += (e_active + self.num_shared_experts) * 3 * d * self.expert_d_ff
+            p += d * self.router_width  # router
+            if self.router_score == "sigmoid":
+                p += self.router_width  # selection bias
         elif not (self.ssm_state > 0 and is_ssm_layer):
             p += 3 * d * self.d_ff
         return p
+
+
+@dataclasses.dataclass(frozen=True)
+class DeepSeekV3Config(ModelConfig):
+    """A ModelConfig with DeepSeek-V3's mechanisms as fields, so a
+    configuration may set them:
+
+    * ``moe_d_ff``: the routed and shared experts' width beside ``d_ff``,
+      the dense layers' width;
+    * held experts: the router scores ``router_experts`` experts; this
+      device holds ``[first_held_expert, first_held_expert + num_experts)``
+      of them and computes only their part, as one device of an
+      expert-parallel deployment (0 => every expert held);
+    * ``router_score`` "sigmoid": the ``noaux_tc`` router (``n_group``
+      groups, ``topk_group`` kept, weights scaled by ``routed_scaling``);
+    * YaRN rope (arXiv:2309.00071) as scalars, since cfg is a static jit
+      argument: ``yarn_factor`` 1.0 is plain rope.
+    """
+    moe_d_ff: int = 0
+    router_experts: int = 0
+    first_held_expert: int = 0
+    router_score: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling: float = 1.0
+    yarn_factor: float = 1.0
+    yarn_original_max: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 1.0
 
 
 # ---------------------------------------------------------------------------
@@ -231,6 +304,10 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
             num_shared_experts=min(cfg.num_shared_experts, 1),
             first_k_dense=min(cfg.first_k_dense, 1),
         )
+        if cfg.moe_d_ff:
+            upd.update(moe_d_ff=min(cfg.moe_d_ff, 256))
+        if cfg.n_group > 1:
+            upd.update(n_group=2, topk_group=1)
     if cfg.attention_kind == "mla":
         upd.update(q_lora_rank=64, kv_lora_rank=32, qk_nope_head_dim=32,
                    qk_rope_head_dim=16, v_head_dim=32, head_dim=48)

@@ -3,6 +3,9 @@
 [arXiv:2412.19437 (V3) / arXiv:2501.12948 (R1)] 671B total / 37B active.
 This is the reference architecture the paper's CloudMatrix-Infer deployment
 (EP320, MLA DP, MTP) targets; included alongside the 10 assigned archs.
+Its router (softmax top-k), single FFN width and plain rope predate the
+published math, which ``deepseek-v3`` registers; configurations that name
+this one keep what they were written against.
 """
 from repro.configs.base import ModelConfig, register
 

@@ -36,6 +36,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.models.attention import NEG_INF, _pick_chunk
 from repro.models.layers import apply_rope, rms_norm
+from repro.models.mla import softmax_scale
 
 
 def mla_prefill_hybrid(p: dict, x: jax.Array, cfg: ModelConfig, mesh: Mesh,
@@ -50,7 +51,7 @@ def mla_prefill_hybrid(p: dict, x: jax.Array, cfg: ModelConfig, mesh: Mesh,
     m = mesh.shape[axis]
     nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     kvr, qr = cfg.kv_lora_rank, cfg.q_lora_rank
-    scale = 1.0 / ((nope + rope) ** 0.5)
+    scale = softmax_scale(cfg)
 
     def body(x_loc, wq_a, q_ln, wq_b, wkv_a, kv_ln, wk_b, wv_b, wo):
         # x_loc is the already-normed layer input (caller applies the layer
@@ -67,7 +68,7 @@ def mla_prefill_hybrid(p: dict, x: jax.Array, cfg: ModelConfig, mesh: Mesh,
         c_kv = rms_norm(kv[..., :kvr], kv_ln, cfg.norm_eps)
         k_rope = apply_rope(kv[..., kvr:][:, :, None, :],
                             jnp.broadcast_to(pos_loc, (b, s_loc)),
-                            cfg.rope_theta)[:, :, 0, :]
+                            cfg.rope_theta, cfg.yarn)[:, :, 0, :]
         latent_loc = jnp.concatenate([c_kv, k_rope], axis=-1)
 
         # ---- All-Gather (post-reduction latents, paper-placed) ----
@@ -82,7 +83,7 @@ def mla_prefill_hybrid(p: dict, x: jax.Array, cfg: ModelConfig, mesh: Mesh,
         q = q.reshape(b, s, h_loc, nope + rope)
         q_nope, q_rope = q[..., :nope], q[..., nope:]
         q_rope = apply_rope(q_rope, jnp.broadcast_to(pos_full, (b, s)),
-                            cfg.rope_theta)
+                            cfg.rope_theta, cfg.yarn)
         c_full, kr_full = latent_full[..., :kvr], latent_full[..., kvr:]
         k_nope = jnp.einsum("bsr,re->bse", c_full, wk_b).reshape(b, s, h_loc, nope)
         v = jnp.einsum("bsr,re->bse", c_full, wv_b).reshape(b, s, h_loc, vd)

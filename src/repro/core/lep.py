@@ -106,6 +106,11 @@ def make_lep_moe_fn(
 
     def moe_fn(p: dict, x: jax.Array, cfg: ModelConfig
                ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        if not cfg.holds_every_expert:
+            raise ValueError(
+                f"{cfg.name}: LEP spreads all of the router's experts over "
+                f"its mesh; this config holds {cfg.num_experts} of "
+                f"{cfg.router_width}")
         t, d = x.shape
         e, k = cfg.num_experts, cfg.num_experts_per_tok
         r = redundancy
@@ -133,9 +138,9 @@ def make_lep_moe_fn(
         w_spec = P(ep_axes, None, ffn_shard_axis)
         wd_spec = P(ep_axes, ffn_shard_axis, None)
 
-        def body(x_loc, valid_loc, router_w, wg_l, wu_l, wd_l):
+        def body(x_loc, valid_loc, router_w, wg_l, wu_l, wd_l, *bias):
             tl = x_loc.shape[0]
-            top_i, top_p, aux = moe_mod.route(router_w, x_loc, cfg)
+            top_i, top_p, aux = moe_mod.route(router_w, x_loc, cfg, *bias)
             # Padded rows: spread over experts, zero combine weight.
             row = jnp.arange(tl, dtype=jnp.int32)
             spread = (row[:, None] * k + jnp.arange(k)[None, :]) % e
@@ -243,12 +248,15 @@ def make_lep_moe_fn(
             dropped = jax.lax.psum(jnp.sum(~in_cap), mesh_axes)
             return out.astype(x_loc.dtype), aux, dropped
 
+        # The sigmoid router's selection bias, replicated, where it has one.
+        bias = tuple(p[n] for n in ("e_score_correction_bias",) if n in p)
         routed, aux, dropped = jax.shard_map(
             body, mesh=mesh,
-            in_specs=(tok_spec, P(mesh_axes), P(), w_spec, w_spec, wd_spec),
+            in_specs=(tok_spec, P(mesh_axes), P(), w_spec, w_spec, wd_spec)
+            + (P(),) * len(bias),
             out_specs=(tok_spec, P(), P()),
             check_vma=False,
-        )(x_pad, valid, p["router"], wg, wu, wd)
+        )(x_pad, valid, p["router"], wg, wu, wd, *bias)
         routed = routed[:t]
 
         # Shared experts: dense, partitioned by the XLA SPMD partitioner

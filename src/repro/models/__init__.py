@@ -4,6 +4,7 @@ from repro.models.model import (  # noqa: F401
     decode_loop,
     decode_loop_mtp,
     decode_step,
+    decode_step_held,
     forward,
     init_params,
     lm_loss,

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.attention import NEG_INF, _pick_chunk
-from repro.models.layers import apply_rope, dense_init, rms_norm
+from repro.models.layers import apply_rope, dense_init, rms_norm, yarn_mscale
 
 
 def init_mla_params(key, cfg: ModelConfig, n_layers: int, dtype) -> dict:
@@ -45,6 +45,15 @@ def init_mla_params(key, cfg: ModelConfig, n_layers: int, dtype) -> dict:
     }
 
 
+def softmax_scale(cfg: ModelConfig) -> float:
+    """``1/sqrt(nope + rope)``, times ``yarn_mscale(factor,
+    mscale_all_dim)**2`` under YaRN, as DeepSeek-V3's attention has it."""
+    scale = 1.0 / ((cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** 0.5)
+    if cfg.yarn is not None:
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
 def _mla_qkv_latent(p: dict, x: jax.Array, cfg: ModelConfig, positions: jax.Array):
     """Shared 'MLAProlog': projections + norms + RoPE (paper fuses these)."""
     b, s, _ = x.shape
@@ -54,12 +63,13 @@ def _mla_qkv_latent(p: dict, x: jax.Array, cfg: ModelConfig, positions: jax.Arra
     q = rms_norm(q, p["q_ln"], cfg.norm_eps)
     q = jnp.einsum("bsr,re->bse", q, p["wq_b"]).reshape(b, s, h, nope + rope)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.yarn)
 
     kv = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"])
     c_kv, k_rope = kv[..., : cfg.kv_lora_rank], kv[..., cfg.kv_lora_rank:]
     c_kv = rms_norm(c_kv, p["kv_ln"], cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0, :]
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                        cfg.yarn)[:, :, 0, :]
     return q_nope, q_rope, c_kv, k_rope
 
 
@@ -127,7 +137,7 @@ def mla_prefill(p: dict, x: jax.Array, cfg: ModelConfig,
 
     k_nope = jnp.einsum("bsr,re->bse", c_kv, p["wk_b"]).reshape(b, s, h, nope)
     vfull = jnp.einsum("bsr,re->bse", c_kv, p["wv_b"]).reshape(b, s, h, vd)
-    scale = 1.0 / ((nope + rope) ** 0.5)
+    scale = softmax_scale(cfg)
 
     chunk = _pick_chunk(s)
     n_chunks = s // chunk
@@ -191,7 +201,7 @@ def mla_decode(p: dict, x: jax.Array, cache: jax.Array, cache_len: jax.Array,
     wk = p["wk_b"].reshape(kvr, h, nope)
     q_lat = jnp.einsum("bshe,rhe->bshr", q_nope.astype(jnp.float32),
                        wk.astype(jnp.float32))
-    scale = 1.0 / ((nope + rope) ** 0.5)
+    scale = softmax_scale(cfg)
     vmask = decode_valid_mask(cache_len, cap, ring=False)        # (B|1,1,S)
 
     if use_kernel and cache_len.ndim == 0:
@@ -252,7 +262,7 @@ def mla_extend(p: dict, x: jax.Array, cache: jax.Array, offset: jax.Array,
     wk = p["wk_b"].reshape(kvr, h, nope)
     q_lat = jnp.einsum("bshe,rhe->bshr", q_nope.astype(jnp.float32),
                        wk.astype(jnp.float32))
-    scale = 1.0 / ((nope + rope) ** 0.5)
+    scale = softmax_scale(cfg)
     ck = cache[..., :kvr].astype(jnp.float32)                # (B,cap,kvr)
     kr = cache[..., kvr:].astype(jnp.float32)                # (B,cap,rope)
     scores = (

@@ -352,20 +352,45 @@ def make_caches(cfg: ModelConfig, batch: int, capacity: int,
 # ---------------------------------------------------------------------------
 
 
+def _held_rows(aux: Dict[str, jax.Array], b: int) -> jax.Array:
+    """Per request, a MoE block's routings onto the experts held here
+    (``moe_capacity``'s ``aux["held"]``; zeros where the MoE fn has none)."""
+    held = aux.get("held")
+    if held is None:
+        return jnp.zeros((b,), jnp.int32)
+    return jnp.sum(held.reshape(b, -1), axis=1, dtype=jnp.int32)
+
+
 def decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
                 caches: Dict[str, Any], cache_len: jax.Array,
                 moe_fn: Optional[MoeFn] = None
                 ) -> Tuple[jax.Array, Dict[str, Any]]:
     """tokens: (B, 1) int32. Returns (logits (B, V), updated caches)."""
+    (logits, _), new_caches = decode_step_held(params, cfg, tokens, caches,
+                                               cache_len, moe_fn)
+    return logits, new_caches
+
+
+def decode_step_held(params: dict, cfg: ModelConfig, tokens: jax.Array,
+                     caches: Dict[str, Any], cache_len: jax.Array,
+                     moe_fn: Optional[MoeFn] = None
+                     ) -> Tuple[Tuple[jax.Array, jax.Array], Dict[str, Any]]:
+    """:func:`decode_step` that also counts, per request, the routings onto
+    held experts over the MoE layers: ((logits (B, V), held (B,)), caches);
+    ``held`` is zero for a model without experts. The step
+    :func:`decode_loop` scans."""
     moe_fn = moe_fn or moe_mod.moe_capacity
     x = params["embed"][tokens].astype(_dtype(cfg))           # (B,1,D)
+    b = x.shape[0]
+    held = jnp.zeros((b,), jnp.int32)
     new_caches: Dict[str, Any] = {}
     for seg in build_plan(cfg):
         seg_params = params["segments"][seg.name]
         cache = caches[seg.name]
         if seg.kind in ("dense", "moe"):
             if cfg.attention_kind == "mla":
-                def body(h, xs):
+                def body(carry, xs):
+                    h, n = carry
                     pl, c = xs
                     with jax.named_scope("attention"):
                         hin = rms_norm(h, pl["attn"]["ln"], cfg.norm_eps)
@@ -373,28 +398,33 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
                                                      cache_len, cfg)
                     h2 = h + out
                     if seg.kind == "moe":
-                        h2, _ = _moe_block(pl["moe"], h2, cfg, moe_fn)
+                        h2, aux = _moe_block(pl["moe"], h2, cfg, moe_fn)
+                        n = n + _held_rows(aux, b)
                     else:
                         h2 = _mlp_block(pl["mlp"], h2, cfg)
-                    return h2, nc
+                    return (h2, n), nc
 
-                x, new_mla = _scan(body, x, (seg_params, cache["mla"]))
+                (x, held), new_mla = _scan(body, (x, held),
+                                           (seg_params, cache["mla"]))
                 new_caches[seg.name] = {"mla": new_mla, "length": cache_len + 1}
             else:
                 ring = (cfg.sliding_window is not None
                         and cache.k.shape[2] == cfg.sliding_window)
 
-                def body(h, xs):
+                def body(carry, xs):
+                    h, n = carry
                     pl, ck, cv = xs
                     h2, nk, nv = _attn_block_decode(pl["attn"], h, cfg, ck, cv,
                                                     cache_len, ring)
                     if seg.kind == "moe":
-                        h2, _ = _moe_block(pl["moe"], h2, cfg, moe_fn)
+                        h2, aux = _moe_block(pl["moe"], h2, cfg, moe_fn)
+                        n = n + _held_rows(aux, b)
                     else:
                         h2 = _mlp_block(pl["mlp"], h2, cfg)
-                    return h2, (nk, nv)
+                    return (h2, n), (nk, nv)
 
-                x, (nk, nv) = _scan(body, x, (seg_params, cache.k, cache.v))
+                (x, held), (nk, nv) = _scan(body, (x, held),
+                                            (seg_params, cache.k, cache.v))
                 new_caches[seg.name] = KVCache(nk, nv, cache_len + 1)
         elif seg.kind == "mamba_tail":
             def body(h, xs):
@@ -439,7 +469,7 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
                 "shared_kv": KVCache(nk, nv, cache_len + 1),
             }
     logits = unembed(params, cfg, x[:, 0:1, :])[:, 0, :]
-    return logits, new_caches
+    return (logits, held), new_caches
 
 
 # ---------------------------------------------------------------------------
@@ -565,7 +595,7 @@ def decode_loop(params: dict, cfg: ModelConfig, tokens: jax.Array,
                 moe_fn: Optional[MoeFn] = None,
                 step_fn: Optional[Callable] = None
                 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array,
-                           Dict[str, Any], jax.Array]:
+                           jax.Array, Dict[str, Any], jax.Array]:
     """``n_steps`` greedy decode iterations in one ``lax.scan`` — N tokens
     per host sync instead of one.
 
@@ -582,13 +612,15 @@ def decode_loop(params: dict, cfg: ModelConfig, tokens: jax.Array,
     and dispatches the widest pre-jitted width that fits
     ``min(steps_left)``, so a slot's remaining budget routinely spans
     multiple dispatches). ``step_fn`` overrides the inner
-    ``(tokens (B,1), caches, cache_len) -> (logits, caches)`` step — the
-    hook the microbatch interleaver wraps.
+    ``(tokens (B,1), caches, cache_len) -> ((logits, held), caches)`` step
+    (:func:`decode_step_held`) — the hook the microbatch interleaver wraps.
 
     Returns ``(emitted (B, n_steps), live (B, n_steps), finite (B, n_steps),
-    tokens (B,), caches, cache_len)``; ``emitted[:, j]`` is meaningful only
-    where ``live[:, j]``; ``finite[:, j]`` says step j's logits row had no
-    NaN or Inf.
+    held (B, n_steps), tokens (B,), caches, cache_len)``; ``emitted[:, j]``
+    is meaningful only where ``live[:, j]``; ``finite[:, j]`` says step j's
+    logits row had no NaN or Inf; ``held[:, j]`` counts a live slot's
+    routings onto held experts over the MoE layers at step j (0 where the
+    slot is not live).
     Chunk-split invariance: because frozen slots hold bit-exactly and live
     slots see the identical per-step computation, any partition of N total
     iterations into scan dispatches emits identical tokens.
@@ -609,7 +641,7 @@ def decode_loop(params: dict, cfg: ModelConfig, tokens: jax.Array,
         mf = moe_fn
 
         def step_fn(t, c, l):  # noqa: E731 — default inner step
-            return decode_step(params, cfg, t, c, l, mf)
+            return decode_step_held(params, cfg, t, c, l, mf)
 
     cap = _cache_capacity(cfg, caches)
     axes = cache_batch_axes(cfg)
@@ -624,7 +656,7 @@ def decode_loop(params: dict, cfg: ModelConfig, tokens: jax.Array,
         live = left > 0
         if cap is not None:
             live &= cl < cap
-        logits, ncs = step_fn(tok[:, None], cs, cl)
+        (logits, held), ncs = step_fn(tok[:, None], cs, cl)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         tok = jnp.where(live, nxt, tok)
         cl = cl + live.astype(jnp.int32)
@@ -633,11 +665,11 @@ def decode_loop(params: dict, cfg: ModelConfig, tokens: jax.Array,
             lambda n, o, ax: _select(live, n, o, ax), ncs, cs, axes)
         ncs = _with_lengths(cfg, ncs, cl)
         fin = jnp.isfinite(logits).all(axis=-1)
-        return (tok, cl, left, ncs), (nxt, live, fin)
+        return (tok, cl, left, ncs), (nxt, live, fin, jnp.where(live, held, 0))
 
-    (tokens, cache_len, _, caches), (em, lv, fin) = jax.lax.scan(
+    (tokens, cache_len, _, caches), (em, lv, fin, held) = jax.lax.scan(
         body, (tokens, cache_len, steps_left, caches), None, length=n_steps)
-    return em.T, lv.T, fin.T, tokens, caches, cache_len
+    return em.T, lv.T, fin.T, held.T, tokens, caches, cache_len
 
 
 # ---------------------------------------------------------------------------

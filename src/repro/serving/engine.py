@@ -361,7 +361,7 @@ class DecodeEngine:
 
         def _make_loop(width: int):
             def pdc_decode_loop(p, t, c, l, left):
-                base = lambda tt, cc, ll: model_mod.decode_step(  # noqa: E731
+                base = lambda tt, cc, ll: model_mod.decode_step_held(  # noqa: E731
                     p, cfg, tt, cc, ll, moe_fn)
                 fn = interleaver.wrap(base, max_batch) \
                     if self.interleaved else base
@@ -580,12 +580,16 @@ class DecodeEngine:
             for i, info in self.slot_mgr.active_slots():
                 left[i] = min(info.payload.remaining, width)
                 resident[i] = info.rid
-            emitted, live, finite, self.cur_tok, self.caches, \
+            emitted, live, finite, held, self.cur_tok, self.caches, \
                 self.cache_len = self._get_loop(width)(
                     self.params, self.cur_tok, self.caches, self.cache_len,
                     jnp.asarray(left))
+        counted = self.cfg.is_moe and obs.TRACER.on
         with obs.span("decode.sync"):
-            em, lv, fin = jax.device_get((emitted, live, finite))
+            em, lv, fin, held = jax.device_get(
+                (emitted, live, finite, held if counted else None))
+        if counted:
+            obs.count("decode.held_routings", int(held.sum()))
         with obs.span("decode.commit"):
             return self._commit_chunk(width, resident, em, lv, fin)
 

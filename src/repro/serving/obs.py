@@ -11,6 +11,9 @@ ends in another (the ``queue.*`` waits); those stay in memory only, with no
 parent. Off, ``span`` returns one shared no-op context manager and records
 nothing, and ``begin``/``end`` return at once.
 
+``count(name, n)`` records a timed counter, ``Count(name, t, n)``, read
+back by ``counters()``; off, it returns at once like ``span``.
+
 Spans are per call, never per block or per layer. The scheduler's
 ``RequestTrace`` is the virtual clock, a deterministic test oracle; these are
 the measured times.
@@ -33,6 +36,13 @@ Spans, with the span they open inside:
   inside it, on the scanned path, ``decode.dispatch`` (the decode loop's
   call), ``decode.sync`` (its outputs' read to the host) and
   ``decode.commit`` (the host's per-slot bookkeeping).
+
+Counters:
+
+* ``decode.held_routings``: per scanned decode call of a MoE model, the
+  (token, k) routings of live slots onto the experts held here, summed over
+  the MoE layers and the call's iterations (``decode_loop``'s ``held``, read
+  in the ``decode.sync`` transfer).
 """
 from __future__ import annotations
 
@@ -51,6 +61,12 @@ class Record(NamedTuple):
     t1: Optional[float]      # None while the span is still open
     parent: Optional[int]    # index of the enclosing span's record
     rid: Any
+
+
+class Count(NamedTuple):
+    name: str
+    t: float                 # host clock when counted
+    n: int
 
 
 class _Noop:
@@ -101,13 +117,15 @@ class Tracer:
         self.records: List[list] = []
         self._stack: List[int] = []                       # open span indices
         self._open: Dict[Tuple[str, Any], float] = {}     # waits begun
+        self.counts: List[Count] = []
 
     def enable(self, on: bool = True) -> None:
         self.on = bool(on)
 
     def reset(self) -> None:
-        """Drop every record and every open span or wait."""
+        """Drop every record, count and open span or wait."""
         self.records, self._stack, self._open = [], [], {}
+        self.counts = []
 
     def snapshot(self) -> List[Record]:
         return [Record(*r) for r in self.records]
@@ -116,6 +134,13 @@ class Tracer:
         if not self.on:
             return _NOOP
         return _Span(self, name, rid)
+
+    def count(self, name: str, n: int) -> None:
+        if self.on:
+            self.counts.append(Count(name, time.perf_counter(), n))
+
+    def counters(self) -> List[Count]:
+        return list(self.counts)
 
     def begin(self, name: str, rid) -> None:
         if self.on:
@@ -134,6 +159,8 @@ enable = TRACER.enable
 reset = TRACER.reset
 snapshot = TRACER.snapshot
 span = TRACER.span
+count = TRACER.count
+counters = TRACER.counters
 begin = TRACER.begin
 end = TRACER.end
 

@@ -10,7 +10,7 @@ from repro.configs import ASSIGNED_ARCHS, get_config, smoke_variant
 from repro.models import decode_step, forward, init_params, prefill
 from repro.train import OptConfig, make_train_step, init_opt_state
 
-ALL_ARCHS = ASSIGNED_ARCHS + ["deepseek-r1"]
+ALL_ARCHS = ASSIGNED_ARCHS + ["deepseek-r1", "deepseek-v3"]
 
 
 @pytest.fixture(scope="module")

@@ -148,6 +148,53 @@ def test_granite_serve_programs_fit_one_v5e(one_chip, program):
                               mem.temp_size_in_bytes, held)
 
 
+@pytest.mark.parametrize("program", ["decode_loop", "prefill_continue"])
+def test_deepseek_v3_cell_programs_fit_one_v5e(one_chip, program):
+    """The ``deepseek.reason-decode`` cell's decode loop (batch 32, scan
+    width 4, held-routing count on, as the decode engine jits it) and its
+    fresh-prefill chunk, at the configuration file's published widths and
+    capacity, fit one chip next to every client's B=1 prefill cache."""
+    from bench import harness
+    conf = harness.load_json(os.path.join(
+        os.path.dirname(__file__), "..", "bench", "configs",
+        "deepseek-v3.ep32.json"))
+    traffic = harness.load_json(os.path.join(
+        os.path.dirname(__file__), "..", "bench", "traffic",
+        "reason-decode.json"))
+    cfg = harness.program_config(conf)
+    dep = conf["deployment"]
+    batch = dep["decode_batch"]
+    cap = (traffic["suffix_tokens"]["max"] + traffic["output_tokens"]["max"]
+           + 8)
+    params = _on(harness.param_shapes(cfg), one_chip)
+
+    def caches(b):
+        return _on(jax.eval_shape(lambda: model_mod.make_caches(
+            cfg, b, cap, jnp.float32)), one_chip)
+
+    rows = _spec((batch,), jnp.int32, one_chip)
+    if program == "decode_loop":
+        def loop(p, t, c, n, left):
+            step = lambda tt, cc, ll: model_mod.decode_step_held(  # noqa: E731
+                p, cfg, tt, cc, ll)
+            return model_mod.decode_loop(p, cfg, t, c, n, dep["decode_chunk"],
+                                         steps_left=left, step_fn=step)
+        lowered = jax.jit(loop, donate_argnums=(2,)).lower(
+            params, rows, caches(batch), rows, rows)
+    else:
+        fn = jax.jit(lambda p, t, c, off: model_mod.prefill_continue(
+            p, cfg, t, c, off), donate_argnums=(2,))
+        lowered = fn.lower(params, _spec((1, dep["prefill_chunk"]), jnp.int32,
+                                         one_chip),
+                           caches(1), _spec((), jnp.int32, one_chip))
+    mem = lowered.compile().memory_analysis()
+    held = traffic["clients"] * sum(
+        a.size * a.dtype.itemsize for a in jax.tree.leaves(caches(1)))
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes + held
+    assert used < HBM_BYTES, (mem.argument_size_in_bytes,
+                              mem.temp_size_in_bytes, held)
+
+
 @pytest.mark.parametrize("tokens", [128, 8192])
 @pytest.mark.parametrize("quantize", [True, False])
 def test_lep_moe_on_four_v5e_has_two_all_to_alls(topo, tokens, quantize):

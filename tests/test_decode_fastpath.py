@@ -12,8 +12,8 @@ import numpy as np
 import pytest
 
 from conftest import smoke
-from repro.models import (decode_loop, decode_step, init_params, make_caches,
-                          prefill, prefill_continue)
+from repro.models import (decode_loop, decode_step, decode_step_held,
+                          init_params, make_caches, prefill, prefill_continue)
 from repro.serving import (DecodeCostModel, MicrobatchInterleaver, Request,
                            SchedulerConfig, ServingSystem,
                            decode_cost_from_roofline)
@@ -74,8 +74,8 @@ def test_decode_loop_matches_sequential(arch):
     _, tok0, caches, cl0 = _prefill_batch(cfg, params)
     n = 4
     seq, caches_s, _ = _sequential(cfg, params, tok0, caches, cl0, n)
-    em, lv, fin, _, caches_l, clf = decode_loop(params, cfg, tok0, caches,
-                                                cl0, n)
+    em, lv, fin, _, _, caches_l, clf = decode_loop(params, cfg, tok0,
+                                                   caches, cl0, n)
     assert np.array_equal(np.asarray(em), seq)
     assert np.asarray(lv).all()
     assert np.asarray(fin).all()
@@ -88,7 +88,7 @@ def test_decode_loop_per_slot_masking(qwen):
     cfg, params = qwen
     _, tok0, caches, cl0 = _prefill_batch(cfg, params)
     seq, _, _ = _sequential(cfg, params, tok0, caches, cl0, 5)
-    em, lv, _, _, caches_m, clm = decode_loop(
+    em, lv, _, _, _, caches_m, clm = decode_loop(
         params, cfg, tok0, caches, cl0, 5,
         steps_left=jnp.asarray([5, 2], jnp.int32))
     em, lv = np.asarray(em), np.asarray(lv)
@@ -112,7 +112,7 @@ def test_decode_loop_capacity_masking(qwen):
     """Slots at cache capacity stop advancing instead of corrupting KV."""
     cfg, params = qwen
     _, tok0, caches, cl0 = _prefill_batch(cfg, params, capacity=14)  # 2 free
-    em, lv, _, _, _, clf = decode_loop(params, cfg, tok0, caches, cl0, 5)
+    em, lv, _, _, _, _, clf = decode_loop(params, cfg, tok0, caches, cl0, 5)
     assert np.asarray(clf).tolist() == [14, 14]
     assert np.asarray(lv)[:, :2].all() and not np.asarray(lv)[:, 2:].any()
 
@@ -121,12 +121,15 @@ def test_decode_loop_interleaved_matches_sequential(qwen):
     """Byte-exactness holds when the inner step is microbatch-interleaved."""
     cfg, params = qwen
     _, tok0, caches, cl0 = _prefill_batch(cfg, params)
-    wrap = MicrobatchInterleaver(2).wrap(
+    interleaver = MicrobatchInterleaver(2)
+    wrap = interleaver.wrap(
         lambda t, c, l: decode_step(params, cfg, t, c, l), 2)
     seq, caches_s, _ = _sequential(cfg, params, tok0, caches, cl0, 4,
                                    step=wrap)
-    em, lv, _, _, caches_l, _ = decode_loop(params, cfg, tok0, caches, cl0,
-                                            4, step_fn=wrap)
+    wrap_held = interleaver.wrap(
+        lambda t, c, l: decode_step_held(params, cfg, t, c, l), 2)
+    em, lv, _, _, _, caches_l, _ = decode_loop(params, cfg, tok0, caches,
+                                               cl0, 4, step_fn=wrap_held)
     assert np.array_equal(np.asarray(em), seq)
     assert _content_equal(caches_s, caches_l)
 

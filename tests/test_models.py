@@ -50,7 +50,7 @@ def test_ssd_chunked_equals_reference():
 
 
 @pytest.mark.parametrize("arch", ["qwen3-8b", "olmoe-1b-7b", "mamba2-780m",
-                                  "zamba2-1.2b", "deepseek-r1"])
+                                  "zamba2-1.2b", "deepseek-r1", "deepseek-v3"])
 def test_decode_continuation_matches_forward(arch):
     """prefill(s tokens) + n decode_steps == forward(s+n tokens) logits."""
     # generous expert capacity: token drops depend on total token count and

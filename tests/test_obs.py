@@ -141,6 +141,39 @@ def test_waits_pair_by_name_and_rid(tracer):
     assert obs.summary(recs)["queue.decode"][0] == 1
 
 
+def test_counts_are_timed_on_and_nothing_off(tracer):
+    tracer.count("decode.held_routings", 5)          # off: nothing
+    assert tracer.counters() == []
+    tracer.enable(True)
+    tracer.count("decode.held_routings", 5)
+    tracer.count("decode.held_routings", 7)
+    got = tracer.counters()
+    assert [(c.name, c.n) for c in got] == [("decode.held_routings", 5),
+                                             ("decode.held_routings", 7)]
+    assert got[0].t <= got[1].t
+    tracer.reset()
+    assert tracer.counters() == []
+
+
+def test_decode_engine_counts_held_routings_of_moe_models_only(granite,
+                                                               tracer):
+    """On, the decode engine counts a MoE model's routings onto held
+    experts: with every expert held, k a MoE layer for each decoded token.
+    A dense model, or the tracer off, records no count."""
+    cfg = smoke("olmoe-1b-7b")
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    serve(cfg, params)
+    assert tracer.counters() == []
+    tracer.enable(True)
+    serve(*granite)
+    assert tracer.counters() == []
+    served = serve(cfg, params)
+    got = [c.n for c in tracer.counters()]
+    decoded = sum(len(t) - 1 for t in served.values())
+    moe_layers = cfg.num_layers - cfg.first_k_dense
+    assert got and sum(got) == decoded * cfg.num_experts_per_tok * moe_layers
+
+
 def test_serve_cli_trace_prints_each_span(monkeypatch, capsys, tracer):
     """``launch/serve.py --trace`` turns the tracer on for the serve call
     and prints each span's count, total and mean host time last."""
