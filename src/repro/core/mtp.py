@@ -151,7 +151,7 @@ def mtp_step(params: dict, mtp: dict, cfg: ModelConfig,
 
     Accepted requests emit 2 tokens and advance 2; rejected requests emit 1,
     advance 1, and their stale slot len+1 is overwritten next iteration by
-    the per-request scatter write (attention.update_cache). This is exactly
+    the per-request scatter write (attention.write_row). This is exactly
     the paper's §4.2.2-(3) regime: effective sequence lengths diverge within
     one batch, handled by per-request (B,) cache_len masks. In the
     memory-bound decode regime the two forwards share one weight stream, so

@@ -223,13 +223,26 @@ def _positions_of(cache_len: jax.Array, b: int) -> jax.Array:
     return cache_len[:, None].astype(jnp.int32)
 
 
-def update_cache(cache: jax.Array, new: jax.Array, slot: jax.Array) -> jax.Array:
-    """Write (B,1,...) entry at per-request or scalar slot into (B,S,...)."""
-    if slot.ndim == 0:
-        return jax.lax.dynamic_update_slice_in_dim(
-            cache, new.astype(cache.dtype), slot, axis=1)
-    b = cache.shape[0]
-    return cache.at[jnp.arange(b), slot].set(new[:, 0].astype(cache.dtype))
+def decode_write_pos(cache_len: jax.Array, cap: int, ring: bool,
+                     live: Optional[jax.Array] = None) -> jax.Array:
+    """Where each request's new decode row goes: ``cache_len`` (mod ``cap``
+    in a ring), and ``cap``, where :func:`write_row` drops it, for a slot
+    that is not ``live`` (B,). Scalar or (B,)."""
+    pos = (cache_len % cap if ring else cache_len).astype(jnp.int32)
+    if live is not None:
+        pos = jnp.where(live, pos, cap)
+    return pos
+
+
+def write_row(stack: jax.Array, layer: jax.Array, new: jax.Array,
+              pos: jax.Array) -> jax.Array:
+    """Write each request's row ``new`` (B, 1, ...) into the layer-stacked
+    cache (L, B, S, ...) at (``layer``, b, ``pos[b]``), one row in place; a
+    ``pos`` of S (the capacity) drops that request's write."""
+    b = stack.shape[1]
+    pos = jnp.broadcast_to(pos, (b,))
+    return stack.at[layer, jnp.arange(b), pos].set(
+        new[:, 0].astype(stack.dtype), mode="drop")
 
 
 def decode_valid_mask(cache_len: jax.Array, cap: int, ring: bool) -> jax.Array:
@@ -250,22 +263,25 @@ def decode_valid_mask(cache_len: jax.Array, cap: int, ring: bool) -> jax.Array:
 def attention_decode(
     p: dict,
     x: jax.Array,                 # (B, 1, D) — one new token per request
-    cache_k: jax.Array,           # (B, S, KV, hd)
+    cache_k: jax.Array,           # (L, B, S, KV, hd), the segment's stack
     cache_v: jax.Array,
     cache_len: jax.Array,         # int32: scalar or per-request (B,)
     cfg: ModelConfig,
-    ring: bool = False,
+    ring: bool,
+    layer: jax.Array,             # int32 index into the stacks' L axis
+    write_pos: jax.Array,         # (B,) or scalar, from decode_write_pos
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One decode step. Returns (out (B,1,D), new_cache_k, new_cache_v)."""
+    """One decode step of layer ``layer``: writes the token's K/V row into
+    the stacks in place at ``write_pos``, then attends over that layer of
+    the stacks. Returns (out (B,1,D), cache_k, cache_v)."""
     b = x.shape[0]
-    cap = cache_k.shape[1]
+    cap = cache_k.shape[2]
     positions = _positions_of(cache_len, b)
     q, k_new, v_new = _project_qkv(p, x, cfg, positions)
-    slot = (cache_len % cap).astype(jnp.int32) if ring else cache_len
-    cache_k = update_cache(cache_k, k_new, slot)
-    cache_v = update_cache(cache_v, v_new, slot)
+    cache_k = write_row(cache_k, layer, k_new, write_pos)
+    cache_v = write_row(cache_v, layer, v_new, write_pos)
     mask = decode_valid_mask(cache_len, cap, ring)
-    out = _sdpa(q, cache_k, cache_v, mask)
+    out = _sdpa(q, cache_k[layer], cache_v[layer], mask)
     out = jnp.einsum("bse,ed->bsd", out, p["wo"])
     return out, cache_k, cache_v
 

@@ -178,24 +178,29 @@ def mla_prefill(p: dict, x: jax.Array, cfg: ModelConfig,
 
 
 def mla_decode(p: dict, x: jax.Array, cache: jax.Array, cache_len: jax.Array,
-               cfg: ModelConfig, use_kernel: bool = False
-               ) -> Tuple[jax.Array, jax.Array]:
-    """Absorbed decode step.
+               cfg: ModelConfig, layer: jax.Array, write_pos: jax.Array,
+               use_kernel: bool = False) -> Tuple[jax.Array, jax.Array]:
+    """Absorbed decode step of layer ``layer``.
 
-    x: (B, 1, D); cache: (B, S, kvr+rope). Returns (out (B,1,D), new cache).
+    x: (B, 1, D); cache: the segment's stacked latents (L, B, S, kvr+rope).
+    Writes the token's latent row in place at ``write_pos`` (see
+    ``attention.decode_write_pos``) and attends over that layer. Returns
+    (out (B,1,D), the stack).
     """
-    from repro.models.attention import _positions_of, decode_valid_mask, update_cache
+    from repro.models.attention import (_positions_of, decode_valid_mask,
+                                        write_row)
 
     b = x.shape[0]
     h = cfg.num_heads
     nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     kvr = cfg.kv_lora_rank
-    cap = cache.shape[1]
+    cap = cache.shape[2]
     positions = _positions_of(cache_len, b)
     q_nope, q_rope, c_kv, k_rope = _mla_qkv_latent(p, x, cfg, positions)
 
     new_entry = jnp.concatenate([c_kv, k_rope], axis=-1)        # (B,1,kvr+rope)
-    cache = update_cache(cache, new_entry, cache_len)
+    cache = write_row(cache, layer, new_entry, write_pos)
+    lat = cache[layer]                                          # (B,S,kvr+rope)
 
     # Absorb W_UK into the query: q_lat (B,1,H,kvr)
     wk = p["wk_b"].reshape(kvr, h, nope)
@@ -208,11 +213,11 @@ def mla_decode(p: dict, x: jax.Array, cache: jax.Array, cache_len: jax.Array,
         from repro.kernels.mla_attention.ops import mla_decode_attention
         valid = jnp.arange(cap, dtype=jnp.int32) <= cache_len
         o_lat = mla_decode_attention(
-            q_lat[:, 0], q_rope[:, 0], cache.astype(jnp.float32), valid, scale, kvr)
+            q_lat[:, 0], q_rope[:, 0], lat.astype(jnp.float32), valid, scale, kvr)
         o_lat = o_lat[:, None]
     else:
-        ck = cache[..., :kvr].astype(jnp.float32)                # (B,S,kvr)
-        kr = cache[..., kvr:].astype(jnp.float32)                # (B,S,rope)
+        ck = lat[..., :kvr].astype(jnp.float32)                  # (B,S,kvr)
+        kr = lat[..., kvr:].astype(jnp.float32)                  # (B,S,rope)
         scores = (
             jnp.einsum("bshr,btr->bhst", q_lat, ck)
             + jnp.einsum("bshe,bte->bhst", q_rope.astype(jnp.float32), kr)

@@ -200,13 +200,19 @@ def _attn_block_prefill(pl_attn, x, cfg, positions):
 
 
 @jax.named_scope("attention")
-def _attn_block_decode(pl_attn, x, cfg, cache_k, cache_v, cache_len, ring):
+def _attn_block_decode(pl_attn, x, cfg, cache_k, cache_v, cache_len, ring,
+                       layer, write_pos):
+    """Layer ``layer`` of a decode step against the segment's stacked
+    caches (MLA: the latents in ``cache_k``, ``cache_v`` None), each written
+    one row in place at ``write_pos``."""
     h = rms_norm(x, pl_attn["ln"], cfg.norm_eps)
     if cfg.attention_kind == "mla":
-        out, new_cache = mla_mod.mla_decode(pl_attn, h, cache_k, cache_len, cfg)
+        out, new_cache = mla_mod.mla_decode(pl_attn, h, cache_k, cache_len,
+                                            cfg, layer, write_pos)
         return x + out, new_cache, None
     out, ck, cv = attn_mod.attention_decode(pl_attn, h, cache_k, cache_v,
-                                            cache_len, cfg, ring)
+                                            cache_len, cfg, ring, layer,
+                                            write_pos)
     return x + out, ck, cv
 
 
@@ -371,61 +377,64 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
     return logits, new_caches
 
 
+def _ring_cache(cfg: ModelConfig, cap: int) -> bool:
+    """A sequence buffer of ``sliding_window`` tokens is written as a ring."""
+    return bool(cfg.sliding_window) and cap == cfg.sliding_window
+
+
 def decode_step_held(params: dict, cfg: ModelConfig, tokens: jax.Array,
                      caches: Dict[str, Any], cache_len: jax.Array,
-                     moe_fn: Optional[MoeFn] = None
+                     moe_fn: Optional[MoeFn] = None,
+                     live: Optional[jax.Array] = None
                      ) -> Tuple[Tuple[jax.Array, jax.Array], Dict[str, Any]]:
     """:func:`decode_step` that also counts, per request, the routings onto
     held experts over the MoE layers: ((logits (B, V), held (B,)), caches);
     ``held`` is zero for a model without experts. The step
-    :func:`decode_loop` scans."""
+    :func:`decode_loop` scans.
+
+    Sequence caches (KV, MLA latents, the hybrid's shared KV) ride the
+    layer scan's carry whole, and each layer writes one row per request
+    into them in place. A request not ``live`` (B,) bool, default all live,
+    keeps every cache leaf bit for bit: its rows go to the capacity index,
+    where the write is dropped; the SSM states, which a step rewrites whole,
+    keep their old value by a select. RoPE positions and the attention mask
+    read ``cache_len`` either way, so a live request computes the same."""
     moe_fn = moe_fn or moe_mod.moe_capacity
     x = params["embed"][tokens].astype(_dtype(cfg))           # (B,1,D)
     b = x.shape[0]
     held = jnp.zeros((b,), jnp.int32)
+
+    def freeze(new, old, ax):
+        return new if live is None else _masked_select(live, new, old, ax, b)
+
     new_caches: Dict[str, Any] = {}
     for seg in build_plan(cfg):
         seg_params = params["segments"][seg.name]
         cache = caches[seg.name]
         if seg.kind in ("dense", "moe"):
-            if cfg.attention_kind == "mla":
-                def body(carry, xs):
-                    h, n = carry
-                    pl, c = xs
-                    with jax.named_scope("attention"):
-                        hin = rms_norm(h, pl["attn"]["ln"], cfg.norm_eps)
-                        out, nc = mla_mod.mla_decode(pl["attn"], hin, c,
-                                                     cache_len, cfg)
-                    h2 = h + out
-                    if seg.kind == "moe":
-                        h2, aux = _moe_block(pl["moe"], h2, cfg, moe_fn)
-                        n = n + _held_rows(aux, b)
-                    else:
-                        h2 = _mlp_block(pl["mlp"], h2, cfg)
-                    return (h2, n), nc
+            mla = cfg.attention_kind == "mla"
+            ck, cv = (cache["mla"], None) if mla else (cache.k, cache.v)
+            cap = ck.shape[2]
+            ring = not mla and _ring_cache(cfg, cap)
+            pos = attn_mod.decode_write_pos(cache_len, cap, ring, live)
 
-                (x, held), new_mla = _scan(body, (x, held),
-                                           (seg_params, cache["mla"]))
-                new_caches[seg.name] = {"mla": new_mla, "length": cache_len + 1}
-            else:
-                ring = (cfg.sliding_window is not None
-                        and cache.k.shape[2] == cfg.sliding_window)
+            def body(carry, xs):
+                h, n, ck, cv = carry
+                pl, layer = xs
+                h2, ck, cv = _attn_block_decode(pl["attn"], h, cfg, ck, cv,
+                                                cache_len, ring, layer, pos)
+                if seg.kind == "moe":
+                    h2, aux = _moe_block(pl["moe"], h2, cfg, moe_fn)
+                    n = n + _held_rows(aux, b)
+                else:
+                    h2 = _mlp_block(pl["mlp"], h2, cfg)
+                return (h2, n, ck, cv), None
 
-                def body(carry, xs):
-                    h, n = carry
-                    pl, ck, cv = xs
-                    h2, nk, nv = _attn_block_decode(pl["attn"], h, cfg, ck, cv,
-                                                    cache_len, ring)
-                    if seg.kind == "moe":
-                        h2, aux = _moe_block(pl["moe"], h2, cfg, moe_fn)
-                        n = n + _held_rows(aux, b)
-                    else:
-                        h2 = _mlp_block(pl["mlp"], h2, cfg)
-                    return (h2, n), (nk, nv)
-
-                (x, held), (nk, nv) = _scan(body, (x, held),
-                                            (seg_params, cache.k, cache.v))
-                new_caches[seg.name] = KVCache(nk, nv, cache_len + 1)
+            (x, held, ck, cv), _ = _scan(
+                body, (x, held, ck, cv),
+                (seg_params, jnp.arange(seg.n_layers, dtype=jnp.int32)))
+            new_caches[seg.name] = ({"mla": ck, "length": cache_len + 1} if mla
+                                    else KVCache(ck, cv, cache_len + 1))
         elif seg.kind == "mamba_tail":
             def body(h, xs):
                 pl, hs, cs = xs
@@ -434,16 +443,21 @@ def decode_step_held(params: dict, cfg: ModelConfig, tokens: jax.Array,
                 return h + out, (nhs, ncs)
 
             x, (nh, nc) = _scan(body, x, (seg_params, cache.h, cache.conv))
-            new_caches[seg.name] = SSMState(nh, nc, cache_len + 1)
+            new_caches[seg.name] = SSMState(freeze(nh, cache.h, 1),
+                                            freeze(nc, cache.conv, 1),
+                                            cache_len + 1)
         else:  # mamba_groups
             g = seg.n_layers // seg.per_group
             grouped = jax.tree.map(
                 lambda a: a.reshape((g, seg.per_group) + a.shape[1:]), seg_params)
-            ring = bool(cfg.sliding_window) and \
-                cache["shared_kv"].k.shape[2] == cfg.sliding_window
+            kv = cache["shared_kv"]
+            cap = kv.k.shape[2]
+            ring = _ring_cache(cfg, cap)
+            pos = attn_mod.decode_write_pos(cache_len, cap, ring, live)
 
-            def group_body(h, xs):
-                pl_group, hs, cs, ck, cv = xs
+            def group_body(carry, xs):
+                h, ck, cv = carry
+                pl_group, hs, cs, group = xs
 
                 def inner(hc, ys):
                     pl, hs1, cs1 = ys
@@ -453,18 +467,20 @@ def decode_step_held(params: dict, cfg: ModelConfig, tokens: jax.Array,
 
                 h, (nhs, ncs) = _scan(inner, h, (pl_group, hs, cs))
                 pl_sa = jax.tree.map(lambda a: a[0], params["shared_attn"])
-                h, nk, nv = _attn_block_decode(pl_sa["attn"], h, cfg, ck, cv,
-                                               cache_len, ring)
+                h, ck, cv = _attn_block_decode(pl_sa["attn"], h, cfg, ck, cv,
+                                               cache_len, ring, group, pos)
                 h = _mlp_block(pl_sa["mlp"], h, cfg)
-                return h, (nhs, ncs, nk, nv)
+                return (h, ck, cv), (nhs, ncs)
 
             ssm = cache["ssm"]
-            x, (nhs, ncs, nk, nv) = _scan(
-                group_body, x,
+            (x, nk, nv), (nhs, ncs) = _scan(
+                group_body, (x, kv.k, kv.v),
                 (grouped, ssm["h"], ssm["conv"],
-                 cache["shared_kv"].k, cache["shared_kv"].v))
+                 jnp.arange(g, dtype=jnp.int32)))
             new_caches[seg.name] = {
-                "ssm": {"h": nhs, "conv": ncs, "length": ssm["length"] + 1},
+                "ssm": {"h": freeze(nhs, ssm["h"], 2),
+                        "conv": freeze(ncs, ssm["conv"], 2),
+                        "length": ssm["length"] + 1},
                 "length": cache_len + 1,
                 "shared_kv": KVCache(nk, nv, cache_len + 1),
             }
@@ -533,13 +549,11 @@ def _cache_capacity(cfg: ModelConfig, caches: Dict[str, Any]) -> Optional[int]:
         if seg.kind in ("dense", "moe"):
             if cfg.attention_kind == "mla":
                 caps.append(c["mla"].shape[2])
-            else:
-                cap = c.k.shape[2]
-                if not (cfg.sliding_window and cap == cfg.sliding_window):
-                    caps.append(cap)
+            elif not _ring_cache(cfg, c.k.shape[2]):
+                caps.append(c.k.shape[2])
         elif seg.kind == "mamba_groups":
             cap = c["shared_kv"].k.shape[2]
-            if not (cfg.sliding_window and cap == cfg.sliding_window):
+            if not _ring_cache(cfg, cap):
                 caps.append(cap)
     return min(caps) if caps else None
 
@@ -552,16 +566,18 @@ def decode_ready_caches(params: dict, cfg: ModelConfig,
     per-slot ``length`` leaves and post-step state dtypes (e.g. the hybrid
     conv window, bf16 after prefill -> f32 after one step; the upcast is
     exact). Keeps ``lax.scan`` carries stable and lets donated cache
-    buffers alias input->output from the very first jitted step."""
+    buffers alias input->output from the very first jitted step.
+    ``step_fn`` has :func:`decode_loop`'s step contract."""
     b = cache_len.shape[0]
     if step_fn is None:
-        def step_fn(t, c, l):
-            return decode_step(params, cfg, t, c, l, moe_fn)
+        def step_fn(t, c, l, live):
+            return decode_step_held(params, cfg, t, c, l, moe_fn, live)
     caches = _with_lengths(cfg, caches, cache_len)
     tok = jnp.zeros((b, 1), jnp.int32)
+    live = jnp.ones((b,), bool)
     for _ in range(2):
         try:
-            out = jax.eval_shape(step_fn, tok, caches, cache_len)[1]
+            out = jax.eval_shape(step_fn, tok, caches, cache_len, live)[1]
         except Exception:       # exotic step_fn: skip dtype stabilization
             break
         if all(c.dtype == o.dtype for c, o in
@@ -603,7 +619,9 @@ def decode_loop(params: dict, cfg: ModelConfig, tokens: jax.Array,
     keeps finished or capacity-full slots frozen: their token, cache content,
     and ``cache_len`` hold bit-exactly while live slots advance, so a chunked
     engine emits token-identical output to ``n_steps`` sequential
-    :func:`decode_step` calls.
+    :func:`decode_step` calls. The step gets the ``live`` mask and drops a
+    frozen slot's cache writes itself (:func:`decode_step_held`), so no
+    iteration copies a whole cache.
 
     tokens: (B,) int32 current token per slot; cache_len: (B,) int32 (scalars
     are broadcast). steps_left: (B,) int32 tokens each slot still wants
@@ -612,8 +630,9 @@ def decode_loop(params: dict, cfg: ModelConfig, tokens: jax.Array,
     and dispatches the widest pre-jitted width that fits
     ``min(steps_left)``, so a slot's remaining budget routinely spans
     multiple dispatches). ``step_fn`` overrides the inner
-    ``(tokens (B,1), caches, cache_len) -> ((logits, held), caches)`` step
-    (:func:`decode_step_held`) — the hook the microbatch interleaver wraps.
+    ``(tokens (B,1), caches, cache_len, live (B,)) -> ((logits, held),
+    caches)`` step (:func:`decode_step_held`), which must leave a slot not
+    ``live`` as it was — the hook the microbatch interleaver wraps.
 
     Returns ``(emitted (B, n_steps), live (B, n_steps), finite (B, n_steps),
     held (B, n_steps), tokens (B,), caches, cache_len)``; ``emitted[:, j]``
@@ -621,9 +640,10 @@ def decode_loop(params: dict, cfg: ModelConfig, tokens: jax.Array,
     logits row had no NaN or Inf; ``held[:, j]`` counts a live slot's
     routings onto held experts over the MoE layers at step j (0 where the
     slot is not live).
-    Chunk-split invariance: because frozen slots hold bit-exactly and live
-    slots see the identical per-step computation, any partition of N total
-    iterations into scan dispatches emits identical tokens.
+    Chunk-split invariance: because frozen slots hold bit-exactly (their
+    writes are dropped) and live slots see the identical per-step
+    computation, any partition of N total iterations into scan dispatches
+    emits identical tokens.
     """
     if tokens.ndim != 1:
         raise ValueError(f"decode_loop wants tokens of shape (B,), "
@@ -640,29 +660,23 @@ def decode_loop(params: dict, cfg: ModelConfig, tokens: jax.Array,
     if step_fn is None:
         mf = moe_fn
 
-        def step_fn(t, c, l):  # noqa: E731 — default inner step
-            return decode_step_held(params, cfg, t, c, l, mf)
+        def step_fn(t, c, l, live):  # noqa: E731 — default inner step
+            return decode_step_held(params, cfg, t, c, l, mf, live)
 
     cap = _cache_capacity(cfg, caches)
-    axes = cache_batch_axes(cfg)
     caches = decode_ready_caches(params, cfg, caches, cache_len,
                                  step_fn=step_fn)
-
-    def _select(mask, new, old, ax):
-        return _masked_select(mask, new, old, ax, b)
 
     def body(carry, _):
         tok, cl, left, cs = carry
         live = left > 0
         if cap is not None:
             live &= cl < cap
-        (logits, held), ncs = step_fn(tok[:, None], cs, cl)
+        (logits, held), ncs = step_fn(tok[:, None], cs, cl, live)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         tok = jnp.where(live, nxt, tok)
         cl = cl + live.astype(jnp.int32)
         left = left - live.astype(jnp.int32)
-        ncs = jax.tree.map(
-            lambda n, o, ax: _select(live, n, o, ax), ncs, cs, axes)
         ncs = _with_lengths(cfg, ncs, cl)
         fin = jnp.isfinite(logits).all(axis=-1)
         return (tok, cl, left, ncs), (nxt, live, fin, jnp.where(live, held, 0))

@@ -361,8 +361,8 @@ class DecodeEngine:
 
         def _make_loop(width: int):
             def pdc_decode_loop(p, t, c, l, left):
-                base = lambda tt, cc, ll: model_mod.decode_step_held(  # noqa: E731
-                    p, cfg, tt, cc, ll, moe_fn)
+                base = lambda tt, cc, ll, live: model_mod.decode_step_held(  # noqa: E731
+                    p, cfg, tt, cc, ll, moe_fn, live)
                 fn = interleaver.wrap(base, max_batch) \
                     if self.interleaved else base
                 return model_mod.decode_loop(p, cfg, t, c, l, width,

@@ -639,11 +639,12 @@ class SLOTracker:
 class MicrobatchInterleaver:
     """Pairs decode microbatches through :func:`core.microbatch.microbatched`.
 
-    Wraps a ``(tokens(B,1), caches, cache_len(B,)) -> (logits, caches)`` step
-    into ``n_micro`` data-independent half-batch computations inside one
-    jitted step, so XLA's latency-hiding scheduler may overlap µb0's MoE
-    dispatch/combine collectives with µb1's attention compute. ``cache_len``
-    rides in the token bundle so it is split along batch like the rest.
+    Wraps a ``(tokens(B,1), caches, cache_len(B,)[, live(B,)]) -> (logits,
+    caches)`` step into ``n_micro`` data-independent half-batch computations
+    inside one jitted step, so XLA's latency-hiding scheduler may overlap
+    µb0's MoE dispatch/combine collectives with µb1's attention compute.
+    ``cache_len`` (and ``decode_loop``'s ``live`` mask) ride in the token
+    bundle so they are split along batch like the rest.
     """
 
     def __init__(self, n_micro: int = 2):
@@ -659,12 +660,12 @@ class MicrobatchInterleaver:
             return step_fn
 
         def core(bundle, caches):
-            return step_fn(bundle["tok"], caches, bundle["len"])
+            return step_fn(bundle["tok"], caches, *bundle["rows"])
 
         mb = microbatched(core, self.n_micro)
 
-        def wrapped(tokens, caches, cache_len):
-            return mb({"tok": tokens, "len": cache_len}, caches)
+        def wrapped(tokens, caches, *rows):
+            return mb({"tok": tokens, "rows": rows}, caches)
 
         return wrapped
 

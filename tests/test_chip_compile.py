@@ -10,6 +10,7 @@ only one process at a time may load the TPU library, and every xdist worker
 imports this file.
 """
 import os
+import re
 import sys
 
 import jax
@@ -91,16 +92,12 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("program", ["decode_step", "decode_loop", "prefill",
-                                     "prefill_continue", "ems_insert"])
-def test_granite_serve_programs_fit_one_v5e(one_chip, program):
-    """Each full-width program chip_smoke's deployment compiles, at its
-    batch and capacity, with caches donated as the engines donate them,
-    fits one chip next to every request's B=1 prefill cache (a closed-loop
-    wave holds them all until decode admits them)."""
+def _granite(one_chip):
+    """chip_smoke's deployment: its flags, the config, the weights' shapes
+    and ``caches(batch)``, the float32 serving caches at its capacity."""
     args = serve.build_parser().parse_args(list(chip_smoke.SERVE_ARGV))
     cfg = get_config(args.arch)
-    batch, cap = args.decode_batch, serve.capacity_for(args)
+    cap = serve.capacity_for(args)
     params = _on(jax.eval_shape(lambda k: model_mod.init_params(k, cfg),
                                 jax.random.PRNGKey(0)), one_chip)
 
@@ -108,6 +105,55 @@ def test_granite_serve_programs_fit_one_v5e(one_chip, program):
         return _on(jax.eval_shape(lambda: model_mod.make_caches(
             cfg, b, cap, jnp.float32)), one_chip)
 
+    return args, cfg, params, caches
+
+
+def _deepseek(one_chip):
+    """The ``deepseek.reason-decode`` cell: its configuration file, its
+    traffic, the program config, the weights' shapes and ``caches(batch)``
+    at the cell's capacity."""
+    from bench import harness
+    here = os.path.dirname(__file__)
+    conf = harness.load_json(os.path.join(
+        here, "..", "bench", "configs", "deepseek-v3.ep32.json"))
+    traffic = harness.load_json(os.path.join(
+        here, "..", "bench", "traffic", "reason-decode.json"))
+    cfg = harness.program_config(conf)
+    cap = (traffic["suffix_tokens"]["max"] + traffic["output_tokens"]["max"]
+           + 8)
+    params = _on(harness.param_shapes(cfg), one_chip)
+
+    def caches(b):
+        return _on(jax.eval_shape(lambda: model_mod.make_caches(
+            cfg, b, cap, jnp.float32)), one_chip)
+
+    return conf, traffic, cfg, params, caches
+
+
+def _lower_decode_loop(cfg, params, caches, batch, width, one_chip):
+    """The decode engine's scanned loop (held-routing count on), caches
+    donated, as ``DecodeEngine`` jits it."""
+    rows = _spec((batch,), jnp.int32, one_chip)
+
+    def loop(p, t, c, n, left):
+        step = lambda tt, cc, ll, live: model_mod.decode_step_held(  # noqa: E731
+            p, cfg, tt, cc, ll, live=live)
+        return model_mod.decode_loop(p, cfg, t, c, n, width, steps_left=left,
+                                     step_fn=step)
+
+    return jax.jit(loop, donate_argnums=(2,)).lower(params, rows, caches,
+                                                    rows, rows)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "decode_loop", "prefill",
+                                     "prefill_continue", "ems_insert"])
+def test_granite_serve_programs_fit_one_v5e(one_chip, program):
+    """Each full-width program chip_smoke's deployment compiles, at its
+    batch and capacity, with caches donated as the engines donate them,
+    fits one chip next to every request's B=1 prefill cache (a closed-loop
+    wave holds them all until decode admits them)."""
+    args, cfg, params, caches = _granite(one_chip)
+    batch = args.decode_batch
     rows = _spec((batch,), jnp.int32, one_chip)
     if program == "decode_step":
         fn = jax.jit(lambda p, t, c, n: model_mod.decode_step(p, cfg, t, c, n),
@@ -121,7 +167,8 @@ def test_granite_serve_programs_fit_one_v5e(one_chip, program):
         lowered = fn.lower(params, rows, caches(batch), rows, rows)
     elif program == "prefill":
         fn = jax.jit(lambda p, t: model_mod.prefill(
-            p, cfg, {"tokens": t}, cap, cache_dtype=jnp.float32))
+            p, cfg, {"tokens": t}, serve.capacity_for(args),
+            cache_dtype=jnp.float32))
         lowered = fn.lower(params, _spec((1, args.prompt_len), jnp.int32,
                                          one_chip))
     elif program == "ems_insert":
@@ -154,33 +201,12 @@ def test_deepseek_v3_cell_programs_fit_one_v5e(one_chip, program):
     width 4, held-routing count on, as the decode engine jits it) and its
     fresh-prefill chunk, at the configuration file's published widths and
     capacity, fit one chip next to every client's B=1 prefill cache."""
-    from bench import harness
-    conf = harness.load_json(os.path.join(
-        os.path.dirname(__file__), "..", "bench", "configs",
-        "deepseek-v3.ep32.json"))
-    traffic = harness.load_json(os.path.join(
-        os.path.dirname(__file__), "..", "bench", "traffic",
-        "reason-decode.json"))
-    cfg = harness.program_config(conf)
+    conf, traffic, cfg, params, caches = _deepseek(one_chip)
     dep = conf["deployment"]
-    batch = dep["decode_batch"]
-    cap = (traffic["suffix_tokens"]["max"] + traffic["output_tokens"]["max"]
-           + 8)
-    params = _on(harness.param_shapes(cfg), one_chip)
-
-    def caches(b):
-        return _on(jax.eval_shape(lambda: model_mod.make_caches(
-            cfg, b, cap, jnp.float32)), one_chip)
-
-    rows = _spec((batch,), jnp.int32, one_chip)
     if program == "decode_loop":
-        def loop(p, t, c, n, left):
-            step = lambda tt, cc, ll: model_mod.decode_step_held(  # noqa: E731
-                p, cfg, tt, cc, ll)
-            return model_mod.decode_loop(p, cfg, t, c, n, dep["decode_chunk"],
-                                         steps_left=left, step_fn=step)
-        lowered = jax.jit(loop, donate_argnums=(2,)).lower(
-            params, rows, caches(batch), rows, rows)
+        batch = dep["decode_batch"]
+        lowered = _lower_decode_loop(cfg, params, caches(batch), batch,
+                                     dep["decode_chunk"], one_chip)
     else:
         fn = jax.jit(lambda p, t, c, off: model_mod.prefill_continue(
             p, cfg, t, c, off), donate_argnums=(2,))
@@ -193,6 +219,48 @@ def test_deepseek_v3_cell_programs_fit_one_v5e(one_chip, program):
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes + held
     assert used < HBM_BYTES, (mem.argument_size_in_bytes,
                               mem.temp_size_in_bytes, held)
+
+
+def _whole_cache_ops(hlo: str, caches) -> list:
+    """Instructions of ``hlo`` that select, slice or update-slice a whole
+    float32 sequence cache of ``caches``: the layer stack (L, B, S, ...),
+    or one layer of it, (B, S, ...) or (1, B, S, ...)."""
+    shapes = set()
+    for a in jax.tree.leaves(caches):
+        if a.ndim > 1:
+            shapes |= {a.shape, a.shape[1:], (1,) + a.shape[1:]}
+    dims = "|".join(re.escape(",".join(map(str, s))) for s in shapes)
+    pat = re.compile(r"= f32\[(%s)\]\{[^}]*\} "
+                     r"(select|dynamic-slice|dynamic-update-slice)\(" % dims)
+    return [m.group(0) for m in pat.finditer(hlo)]
+
+
+@pytest.mark.parametrize("cell", ["granite", "deepseek"])
+def test_decode_loop_writes_one_row_in_place(one_chip, cell):
+    """The scanned decode loop, at chip_smoke's granite widths and at the
+    ``deepseek.reason-decode`` cell's, neither selects nor slices and
+    restacks a whole sequence cache: each layer scatters one row per slot
+    into the stack (a frozen slot's to the capacity index, dropped). The
+    granite loop's temporaries stay under 3 GiB (a whole-cache select and
+    restack took 4.4 GB)."""
+    if cell == "granite":
+        args, cfg, params, caches = _granite(one_chip)
+        batch, width = args.decode_batch, args.decode_chunk
+    else:
+        conf, _, cfg, params, caches = _deepseek(one_chip)
+        dep = conf["deployment"]
+        batch, width = dep["decode_batch"], dep["decode_chunk"]
+    c = caches(batch)
+    compiled = _lower_decode_loop(cfg, params, c, batch, width,
+                                  one_chip).compile()
+    hlo = compiled.as_text()
+    assert _whole_cache_ops(hlo, c) == []
+    stacks = {",".join(map(str, a.shape)) for a in jax.tree.leaves(c)
+              if a.ndim > 1}
+    assert any(f"= f32[{s}]" in line and " scatter(" in line
+               for s in stacks for line in hlo.splitlines())
+    if cell == "granite":
+        assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
 
 
 @pytest.mark.parametrize("tokens", [128, 8192])

@@ -2,6 +2,7 @@
 (`model.decode_loop`), chunked suffix prefill (`model.prefill_continue`),
 batched EMS block packing, single-collective quantized LEP dispatch, and the
 chunked serving path end-to-end."""
+import dataclasses
 import os
 import subprocess
 import sys
@@ -14,6 +15,7 @@ import pytest
 from conftest import smoke
 from repro.models import (decode_loop, decode_step, decode_step_held,
                           init_params, make_caches, prefill, prefill_continue)
+from repro.models.attention import is_ring
 from repro.serving import (DecodeCostModel, MicrobatchInterleaver, Request,
                            SchedulerConfig, ServingSystem,
                            decode_cost_from_roofline)
@@ -83,9 +85,25 @@ def test_decode_loop_matches_sequential(arch):
     assert _content_equal(caches_s, caches_l)
 
 
-def test_decode_loop_per_slot_masking(qwen):
+#: The cache families decode_loop freezes slots of: GQA KV, MLA latents,
+#: MoE, the hybrid's shared KV beside its SSM states, and a ring (granite
+#: with an 8-token window, so a 14- or 32-token cache is written as a ring).
+MASKING_CASES = ["qwen3-8b", "deepseek-r1", "olmoe-1b-7b", "zamba2-1.2b",
+                 "granite-ring"]
+
+
+def _masking_case(case):
+    if case == "granite-ring":
+        cfg = dataclasses.replace(smoke("granite-3-2b"), sliding_window=8)
+    else:
+        cfg = smoke(case)
+    return cfg, init_params(jax.random.PRNGKey(0), cfg)
+
+
+@pytest.mark.parametrize("case", MASKING_CASES)
+def test_decode_loop_per_slot_masking(case):
     """A slot whose steps_left runs out mid-chunk freezes bit-exactly."""
-    cfg, params = qwen
+    cfg, params = _masking_case(case)
     _, tok0, caches, cl0 = _prefill_batch(cfg, params)
     seq, _, _ = _sequential(cfg, params, tok0, caches, cl0, 5)
     em, lv, _, _, _, caches_m, clm = decode_loop(
@@ -108,13 +126,21 @@ def test_decode_loop_per_slot_masking(qwen):
     assert all(oks)
 
 
-def test_decode_loop_capacity_masking(qwen):
-    """Slots at cache capacity stop advancing instead of corrupting KV."""
-    cfg, params = qwen
+@pytest.mark.parametrize("case", MASKING_CASES)
+def test_decode_loop_capacity_masking(case):
+    """Slots at cache capacity stop advancing instead of corrupting KV, and
+    their caches hold bit for bit what two sequential steps leave. A ring
+    has no capacity to reach: its slots decode on as sequential steps do."""
+    cfg, params = _masking_case(case)
     _, tok0, caches, cl0 = _prefill_batch(cfg, params, capacity=14)  # 2 free
-    em, lv, _, _, _, _, clf = decode_loop(params, cfg, tok0, caches, cl0, 5)
-    assert np.asarray(clf).tolist() == [14, 14]
-    assert np.asarray(lv)[:, :2].all() and not np.asarray(lv)[:, 2:].any()
+    em, lv, _, _, _, caches_l, clf = decode_loop(params, cfg, tok0, caches,
+                                                 cl0, 5)
+    n = 5 if is_ring(cfg, 14) else 2
+    seq, caches_s, _ = _sequential(cfg, params, tok0, caches, cl0, n)
+    assert np.asarray(clf).tolist() == [12 + n] * 2
+    assert np.asarray(lv)[:, :n].all() and not np.asarray(lv)[:, n:].any()
+    assert np.array_equal(np.asarray(em)[:, :n], seq)
+    assert _content_equal(caches_s, caches_l)
 
 
 def test_decode_loop_interleaved_matches_sequential(qwen):
@@ -127,7 +153,8 @@ def test_decode_loop_interleaved_matches_sequential(qwen):
     seq, caches_s, _ = _sequential(cfg, params, tok0, caches, cl0, 4,
                                    step=wrap)
     wrap_held = interleaver.wrap(
-        lambda t, c, l: decode_step_held(params, cfg, t, c, l), 2)
+        lambda t, c, l, live: decode_step_held(params, cfg, t, c, l,
+                                               live=live), 2)
     em, lv, _, _, _, caches_l, _ = decode_loop(params, cfg, tok0, caches,
                                                cl0, 4, step_fn=wrap_held)
     assert np.array_equal(np.asarray(em), seq)

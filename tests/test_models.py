@@ -25,8 +25,8 @@ def test_mla_absorbed_equals_naive():
     # decode the last token against the cache of the first s-1
     cache = jnp.zeros((b, s + 4, latent.shape[-1]))
     cache = cache.at[:, : s - 1].set(latent[:, : s - 1])
-    out_dec, _ = mla_mod.mla_decode(p, x[:, s - 1:], cache,
-                                    jnp.int32(s - 1), cfg)
+    out_dec, _ = mla_mod.mla_decode(p, x[:, s - 1:], cache[None],
+                                    jnp.int32(s - 1), cfg, 0, jnp.int32(s - 1))
     np.testing.assert_allclose(np.asarray(out_dec[:, 0]),
                                np.asarray(full_out[:, -1]),
                                rtol=2e-4, atol=2e-4)
