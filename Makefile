@@ -9,7 +9,7 @@
 PY ?= python
 PYTHONPATH := src
 
-.PHONY: test smoke serve-demo bench-slo bench-smoke bench-check ci
+.PHONY: test smoke serve-demo ci
 
 test:
 	PYTHONPATH=$(PYTHONPATH) $(PY) -m pytest -q
@@ -23,129 +23,4 @@ serve-demo:
 	    --n-requests 6 --prompt-len 24 --max-new 8 \
 	    --policy round_robin --tpot-budget-ms 9 --admission shed --trace
 
-bench-slo:
-	PYTHONPATH=$(PYTHONPATH) $(PY) -m benchmarks.run --only tpot_slo
-
-# Live-smoke perf rows only (no dry-run compiles); writes BENCH_decode.json
-# and BENCH_prefill.json at the repo root for PR-over-PR tracking.
-# bench_mtp runs after bench_decode_throughput: it merges the MTP section
-# (acceptance rate + fused-MTP speedup) into the same BENCH_decode.json.
-# bench-check (its own CI step, and part of `make ci`) asserts the decode
-# artifact is schema 7: the pool autoscale section (engine-count timeline
-# + scale-event counts), the continuous_batching section (dead-slot rate
-# before/after, mid-scan refill counts, token identity, zero TPOT budget
-# violations), the fault_tolerance section (crash fired, every lost
-# request recovered by replay, recovery-TTFT percentiles present, faulted
-# tokens bit-identical to the fault-free reference) AND the slo_classes
-# section (interactive TPOT p99 held with class-aware control / violated
-# without on the identical burst, >= 1 mid-decode batch preemption, and
-# preempted-then-resumed tokens bit-identical to the uncontended run).
-# The prefill artifact is schema 9: the handoff_overlap section (pipelined
-# chunked KV streaming strictly lowers virtual-clock TTFT vs the
-# synchronous whole-request handoff, hides transfer time behind prefill,
-# and stays token-identical) AND the ems section (multi-turn session hit
-# rate growing across turns through the shared EMS tier, promote/demote
-# byte conservation against the RDMA-plane transfer books, TTFT split by
-# hit depth, analytic UB-vs-VPC reuse gain, and the hit-aware admission
-# demo: the suffix-blind gate waits where the hit-aware gate admits).
-bench-smoke:
-	PYTHONPATH=$(PYTHONPATH) $(PY) -m benchmarks.bench_decode_throughput --smoke
-	PYTHONPATH=$(PYTHONPATH) $(PY) -m benchmarks.bench_mtp --smoke
-	PYTHONPATH=$(PYTHONPATH) $(PY) -m benchmarks.bench_prefill_throughput --smoke
-	PYTHONPATH=$(PYTHONPATH) $(PY) -m benchmarks.bench_context_caching --smoke
-
-bench-check:
-	$(PY) -c "import json; d = json.load(open('BENCH_decode.json')); \
-	assert d['schema'] == 7, f'BENCH_decode.json schema {d[\"schema\"]} != 7'; \
-	a = d['pool']['autoscale']; \
-	assert a['engine_count_timeline'] and 'scale_grows' in a \
-	and 'scale_shrinks' in a, 'autoscale section incomplete'; \
-	assert a['tokens_identical_to_fixed_pool'] is True, \
-	'autoscaled tokens diverged from the fixed-size pool'; \
-	cb = d['continuous_batching']; \
-	assert cb['tokens_identical_to_per_step'] is True, \
-	'continuous-batching tokens diverged from per-step decode'; \
-	assert cb['after']['dead_slot_rate'] < cb['before']['dead_slot_rate'], \
-	'continuous batching did not lower the dead-slot rate'; \
-	assert cb['after']['mid_scan_refills'] >= 0 \
-	and 'mid_scan_refills' in cb['before'], 'refill counts missing'; \
-	assert cb['tpot_budget_violations'] == 0, \
-	f\"TPOT gate violated {cb['tpot_budget_violations']}x under CB\"; \
-	ft = d['fault_tolerance']; \
-	assert ft['engine_failures'] >= 1 and ft['recoveries'] >= 1, \
-	'fault plan fired no mid-decode crash/recovery'; \
-	assert ft['tokens_replayed'] >= 1 and ft['retries'] >= 1, \
-	'replay/retry counters missing or zero'; \
-	assert ft['recovery_ttft_p50_s'] is not None \
-	and ft['recovery_ttft_p99_s'] is not None, \
-	'recovery-TTFT percentiles missing'; \
-	assert ft['completed'] == ft['completed_fault_free'], \
-	'faulted run lost requests vs fault-free reference'; \
-	assert ft['tokens_identical_to_fault_free'] is True, \
-	'recovered tokens diverged from the fault-free run'; \
-	sc = d['slo_classes']; \
-	assert sc['held_with_control'] is True, \
-	'class-aware control failed to hold interactive TPOT p99'; \
-	assert sc['violated_without_control'] is True, \
-	'class-blind baseline did not violate the budget (burst too mild)'; \
-	assert sc['preemptions'] >= 1, 'no batch-tier preemption fired'; \
-	assert sc['tokens_identical_after_preemption'] is True, \
-	'preempted-then-resumed tokens diverged from the uncontended run'; \
-	print('BENCH_decode.json schema 7 OK:', \
-	f\"{a['scale_grows']} grows, {a['scale_shrinks']} shrinks, \" \
-	f\"peak {a['peak_engines']} engines; dead_slot_rate \" \
-	f\"{cb['before']['dead_slot_rate']} -> {cb['after']['dead_slot_rate']} \" \
-	f\"({cb['after']['mid_scan_refills']} mid-scan refills); \" \
-	f\"{ft['engine_failures']} failures -> {ft['recoveries']} recoveries, \" \
-	f\"{ft['tokens_replayed']} tokens replayed, {ft['retries']} retries; \" \
-	f\"SLO held {sc['interactive_tpot_p99_ms_controlled']:.1f}ms <= \" \
-	f\"{sc['budget_ms']:g}ms < \" \
-	f\"{sc['interactive_tpot_p99_ms_uncontrolled']:.1f}ms blind, \" \
-	f\"{sc['preemptions']} preemptions, \" \
-	f\"brownout peak L{sc['brownout_peak_level']}\")"
-	$(PY) -c "import json; p = json.load(open('BENCH_prefill.json')); \
-	assert p['schema'] == 9, f'BENCH_prefill.json schema {p[\"schema\"]} != 9'; \
-	h = p['handoff_overlap']; \
-	assert h['tokens_identical'] is True, \
-	'streamed handoff tokens diverged from the synchronous path'; \
-	assert h['streamed_ttft_p50_s'] < h['sync_ttft_p50_s'], \
-	'pipelined streaming did not lower median TTFT vs synchronous'; \
-	assert h['streamed_ttft_mean_s'] < h['sync_ttft_mean_s'], \
-	'pipelined streaming did not lower mean TTFT vs synchronous'; \
-	assert h['overlap_hidden_s'] > 0, 'no transfer time was hidden'; \
-	assert h['stream_chunks'] > h['requests'], \
-	'streaming did not actually chunk the handoff'; \
-	assert h['stream_bytes'] > 0 and h['max_chunk_bytes_in_flight'] > 0, \
-	'transfer-bytes-in-flight accounting missing'; \
-	e = p['ems']; hr = e['hit_rate_by_turn']; \
-	assert hr[0] == 0 and hr[-1] > hr[0], \
-	f'EMS hit rate did not grow across session turns: {hr}'; \
-	assert e['hit_rate'] > 0, 'EMS served no hits on the session trace'; \
-	assert e['demote_bytes'] > 0, 'EMS write-back moved no bytes'; \
-	assert e['demote_bytes'] == e['transfer_bytes_demoted'], \
-	'EMS demote bytes diverged from the RDMA-plane transfer books'; \
-	assert e['promote_bytes'] == e['transfer_bytes_promoted'], \
-	'EMS promote bytes diverged from the RDMA-plane transfer books'; \
-	t = e['ttft_by_hit_depth']; \
-	assert t['cold']['n'] > 0 and t['cold']['ttft_ms'] is not None, \
-	'TTFT-by-hit-depth cold bucket empty'; \
-	assert t['deep']['n'] > 0 and t['deep']['ttft_ms'] is not None, \
-	'TTFT-by-hit-depth deep bucket empty (sessions never reused deeply)'; \
-	assert e['ub_vs_vpc_reuse90_gain'] > 1, \
-	'UB plane showed no TTFT gain over VPC at 90% reuse'; \
-	d = e['hit_aware_admission']; \
-	assert d['suffix_blind_decision'] == 'wait', \
-	'demo gate was not saturated: blind gate admitted'; \
-	assert d['hit_aware_decision'] == 'admit', \
-	'hit-aware gate failed to admit the mostly-cached request'; \
-	print('BENCH_prefill.json schema 9 OK:', \
-	f\"streamed TTFT p50 {h['streamed_ttft_p50_s']*1e3:.3f}ms < \" \
-	f\"sync {h['sync_ttft_p50_s']*1e3:.3f}ms, \" \
-	f\"{h['overlap_hidden_s']*1e3:.3f}ms hidden over \" \
-	f\"{h['stream_chunks']} chunks; \" \
-	f\"ems hit rate {hr[0]} -> {hr[-1]} over {e['turns']} turns, \" \
-	f\"{e['demote_bytes']} B demoted / {e['promote_bytes']} B promoted, \" \
-	f\"hit-aware {d['suffix_blind_decision']} -> \" \
-	f\"{d['hit_aware_decision']} at charge {d['suffix_charge']}\")"
-
-ci: smoke test bench-smoke bench-check
+ci: smoke test

@@ -22,9 +22,9 @@ Three modes:
   where the paper's +44% iteration latency (Fig. 22b) comes from.
 * ``model.decode_loop_mtp`` — N MTP iterations in one ``lax.scan`` (the
   device-resident serving fast path; see models/model.py).
-* benchmarks model the paper's 70% single-token acceptance when comparing
-  against SGLang "Simulated MTP" (paper Table 4); ``fit_draft_head``
-  distills a smoke-scale draft head so live benches measure real acceptance.
+* ``fit_draft_head`` distills a smoke-scale draft head, so acceptance is
+  measured rather than assumed (the cost model's default is the paper's
+  70% single-token acceptance, Table 4).
 """
 from __future__ import annotations
 

@@ -14,7 +14,7 @@
       [--tpot-budget-ms 15 --admission queue|shed] [--interleave] \
       [--batch-tpot-budget-ms 45 --batch-admission queue|shed \
        --interactive-frac 0.7 [--preempt-batch] [--brownout]] \
-      [--decode-chunk 4 [--continuous-batching]] [--prefill-chunk 32] \
+      [--decode-chunk 4] [--prefill-chunk 32] \
       [--poisson-rate 100 [--open-loop]] \
       [--production [--arrival-shape poisson|burst|diurnal]] \
       [--seed 0] [--trace] \
@@ -168,15 +168,10 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--interleave", action="store_true",
                     help="pair two decode microbatches per step (§4.2.3)")
     ap.add_argument("--decode-chunk", type=int, default=1,
-                    help="decode iterations per host sync (scanned "
-                         "device-resident decode fast path; with --mtp each "
-                         "iteration speculates, so up to 2x tokens)")
-    ap.add_argument("--continuous-batching", action="store_true",
-                    help="adaptive scan widths + mid-scan slot refill on "
-                         "the chunked fast path: shrink the next chunk to "
-                         "where a finish or gate-held admission lands, and "
-                         "refill freed slots between engine chunks (see "
-                         "dead_slot_rate / mid_scan_refills in the summary)")
+                    help="widest scanned decode chunk per host sync; each "
+                         "turn shrinks it to where a finish or a gate-held "
+                         "admission lands (with --mtp each iteration "
+                         "speculates, so up to 2x tokens)")
     ap.add_argument("--prefill-chunk", type=int, default=None,
                     help="run fresh prompts through chunked prefill_continue "
                          "calls of this width (bounded compile shapes)")
@@ -314,8 +309,6 @@ def build(args: argparse.Namespace) -> Deployment:
                            brownout=args.brownout or None,
                            interleave=args.interleave,
                            decode_chunk=args.decode_chunk,
-                           continuous_batching=args.continuous_batching
-                           or None,
                            prefill_chunk=args.prefill_chunk,
                            degrade_shed_queue_s=args.degrade_shed_queue_s,
                            hit_aware_admission=args.hit_aware_admission

@@ -9,8 +9,6 @@ pool; a metadata service maps (model, version) -> block keys. Loading:
 * warm (hit): DRAM -> NPU over UB (~5 s for 671 GB across the pool).
 
 Versioning: block keys embed the version; stale versions age out via LRU.
-The benchmark ``benchmarks/model_caching.py`` reproduces Table 2 from this
-cost model.
 """
 from __future__ import annotations
 

@@ -11,8 +11,7 @@ the paper's three software roles:
 
 A :class:`SimClock` + :class:`PlaneModel` charge every transfer with the
 bandwidth/latency of the plane it crosses (UB vs VPC vs SSD vs OBS), using
-the paper's published constants (Table 1, §4.4.3), so benchmarks reproduce
-Table 2 / Fig. 23 semantics quantitatively.
+the paper's published constants (Table 1, §4.4.3).
 """
 from __future__ import annotations
 

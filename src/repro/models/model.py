@@ -626,7 +626,7 @@ def decode_loop(params: dict, cfg: ModelConfig, tokens: jax.Array,
     tokens: (B,) int32 current token per slot; cache_len: (B,) int32 (scalars
     are broadcast). steps_left: (B,) int32 tokens each slot still wants
     (defaults to ``n_steps`` everywhere; may exceed ``n_steps`` — the
-    continuous-batching engine jits this function at several scan widths
+    decode engine jits this function at several scan widths
     and dispatches the widest pre-jitted width that fits
     ``min(steps_left)``, so a slot's remaining budget routinely spans
     multiple dispatches). ``step_fn`` overrides the inner
@@ -722,7 +722,7 @@ def decode_loop_mtp(params: dict, mtp: dict, cfg: ModelConfig,
     tokens/drafts: (B,) int32 — last committed token and its proposed
     successor (:func:`repro.core.mtp.propose_draft`). steps_left: (B,)
     tokens each slot still wants (defaults to ``2*n_iters``; may exceed
-    what ``n_iters`` can drain — the continuous-batching engine dispatches
+    what ``n_iters`` can drain — the decode engine dispatches
     several pre-jitted widths against the same remaining budgets, and
     greedy accept/reject is PRNG-independent so any width split commits
     identical tokens). Returns

@@ -333,22 +333,11 @@ class DecodeEngine:
             warnings.warn("decode microbatch interleaving requested but "
                           f"disabled: {reason}", stacklevel=2)
 
-        def pdc_decode_step(p, t, c, l):
-            base = lambda tt, cc, ll: model_mod.decode_step(  # noqa: E731
-                p, cfg, tt, cc, ll, moe_fn)
-            fn = interleaver.wrap(base, max_batch) if self.interleaved else base
-            return fn(t, c, l)
-
-        # Cache buffers are donated so each jitted step reuses them in
-        # place instead of allocating + copying a fresh cache per token.
-        self._step = jax.jit(pdc_decode_step, donate_argnums=(2,))
-
-        # Continuous batching jits the scan at a small ladder of widths
-        # (powers of two up to decode_chunk, plus decode_chunk itself) so
-        # the effective chunk can shrink to where a refill or a finish
-        # lands without recompiling per width request. Loops jit lazily:
-        # a wave that never shrinks compiles exactly one program, same as
-        # before.
+        # The scanned loop is jitted at a small ladder of widths (powers of
+        # two up to decode_chunk, plus decode_chunk itself) so each turn's
+        # width can shrink to where a refill or a finish lands without
+        # recompiling per width request. Loops jit lazily: a wave that never
+        # shrinks compiles exactly one program.
         self._chunk_widths = sorted(
             {w for w in (1 << p for p in range(self.decode_chunk.bit_length()))
              if w <= self.decode_chunk} | {self.decode_chunk})
@@ -359,6 +348,8 @@ class DecodeEngine:
         self.live_slot_iters = 0
         self.dead_slot_iters = 0
 
+        # Cache buffers are donated so each dispatch updates them in place
+        # instead of allocating and copying a fresh cache.
         def _make_loop(width: int):
             def pdc_decode_loop(p, t, c, l, left):
                 base = lambda tt, cc, ll, live: model_mod.decode_step_held(  # noqa: E731
@@ -373,14 +364,9 @@ class DecodeEngine:
         if use_mtp:
             self._propose = jax.jit(
                 lambda p, mp, t: mtp_mod.propose_draft(p, mp, cfg, t))
-            self._mtp_step = jax.jit(
-                lambda p, mp, x, d, c, l, k: mtp_mod.mtp_step(
-                    p, mp, cfg, x, d, c, l, k, moe_fn,
-                    fused_verify=self.mtp_fused),
-                donate_argnums=(4,))
 
-            # Scanned MTP fast path: `width` speculative iterations (up
-            # to 2*width tokens) per host sync, cache donated.
+            # `width` speculative iterations (up to 2*width tokens) per
+            # host sync.
             def _make_loop_mtp(width: int):
                 return jax.jit(
                     lambda p, mp, x, d, c, l, left, k:
@@ -403,7 +389,7 @@ class DecodeEngine:
         return self._loops_mtp[width]
 
     def _effective_chunk(self, refill_pending: bool) -> int:
-        """Continuous batching: the scan width for the next dispatch.
+        """The scan width for the next dispatch.
 
         Shrink from ``decode_chunk`` to where the next host sync can do
         useful work: ``min(remaining)`` across active slots (a slot
@@ -481,16 +467,17 @@ class DecodeEngine:
         """One host-sync decode turn. Returns requests finished this turn."""
         return self.step_chunk()[0]
 
-    def step_chunk(self, continuous: bool = False,
-                   refill_pending: bool = False
+    def step_chunk(self, refill_pending: bool = False
                    ) -> Tuple[List[RequestResult],
                               List[Tuple[List[int], List[int],
                                          dict, List[int]]]]:
-        """One host-sync decode turn: ``decode_chunk`` device iterations per
-        jitted call on the fast path (one otherwise). ``continuous``
-        enables adaptive chunk sizing (:meth:`_effective_chunk`):
-        ``refill_pending`` then signals a gate-held admission that could
-        land in a free slot, pulling the next host sync forward.
+        """One host-sync decode turn: one jitted scan of
+        :func:`~repro.models.model.decode_loop` (``decode_loop_mtp`` under
+        MTP) at the width :meth:`_effective_chunk` picks;
+        ``refill_pending`` signals a gate-held admission that could land
+        in a free slot, pulling the next host sync forward. Slot
+        accounting is reconciled in :meth:`_commit_chunk` as the chunk
+        drains, iteration by iteration.
 
         Returns ``(finished, iter_log)``; ``iter_log`` holds one
         ``(live_rids, finished_rids, tokens_by_rid, masked_rids)`` entry
@@ -498,107 +485,58 @@ class DecodeEngine:
         attribute virtual-clock time per-iteration to the slots that did
         work — and credit the tokens each iteration committed (MTP:
         1+accepted) — while ``masked_rids`` (resident at dispatch but
-        ``lv[i, j]`` false) feed the dead-slot counters without being
-        charged as batch occupancy.
+        ``lv[i, j]`` false: capacity-frozen) feed the dead-slot counters
+        without being charged as batch occupancy.
         """
         with obs.span("decode.chunk"):
-            if self.decode_chunk > 1:
-                width = (self._effective_chunk(refill_pending) if continuous
-                         else self.decode_chunk)
-                return (self._step_chunked_mtp(width) if self.use_mtp
-                        else self._step_chunked(width))
-            return self._step_once()
-
-    def _step_once(self) -> Tuple[List[RequestResult],
-                                  List[Tuple[List[int], List[int],
-                                             dict, List[int]]]]:
-        """One device iteration, one host sync: the path when
-        ``decode_chunk`` is 1."""
-        self.iters += 1
-        active_rids = [info.rid for _, info in self.slot_mgr.active_slots()]
-        self.key, sub = jax.random.split(self.key)
-        if self.use_mtp:
-            emitted, accepted, x_next, d_next, self.caches, self.cache_len = \
-                self._mtp_step(self.params, self.mtp_params, self.cur_tok,
-                               self.draft_tok, self.caches, self.cache_len, sub)
-            self.cur_tok, self.draft_tok = x_next, d_next
-            em = np.asarray(emitted)
-            acc = np.asarray(accepted)
-            fin = np.ones(self.b, bool)
-        else:
-            logits, self.caches = self._step(self.params, self.cur_tok[:, None],
-                                             self.caches, self.cache_len)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            self.cache_len = self.cache_len + 1
-            self.cur_tok = nxt
-            em, fin = jax.device_get((nxt[:, None],
-                                      jnp.isfinite(logits).all(axis=-1)))
-            acc = np.zeros(self.b, bool)
-
-        finished = []
-        tokens_by_rid: dict = {}
-        for i, info in list(self.slot_mgr.active_slots()):
-            slot: _Slot = info.payload
-            slot.result.decode_iters += 1
-            slot.result.nonfinite_logits += int(not fin[i])
-            # Mirror the device-side cache growth (MTP appends the accepted
-            # draft token too) with capacity enforcement.
-            self.slot_mgr.advance(i, 2 if (self.use_mtp and acc[i]) else 1)
-            new_toks = [int(em[i, 0])]
-            if self.use_mtp and acc[i] and slot.remaining > 1:
-                new_toks.append(int(em[i, 1]))
-            committed = 0
-            for t in new_toks:
-                if slot.remaining > 0:
-                    slot.result.tokens.append(t)
-                    slot.remaining -= 1
-                    committed += 1
-            tokens_by_rid[info.rid] = committed
-            if slot.remaining <= 0:
-                finished.append(slot.result)
-                self.slot_mgr.release(i)
-        # Per-step decode never masks a resident slot (capacity overflow
-        # raises in advance() instead) — the dead-slot set is empty.
-        self.live_slot_iters += len(active_rids)
-        return finished, [(active_rids, [r.rid for r in finished],
-                           tokens_by_rid, [])]
-
-    def _step_chunked(self, width: int) -> Tuple[
-            List[RequestResult],
-            List[Tuple[List[int], List[int], dict, List[int]]]]:
-        """Device-resident fast path: ``width`` scanned iterations, one
-        host sync. Slot accounting is reconciled in DecodeSlotManager.advance
-        as the chunk drains, iteration by iteration. The live/masked split
-        per iteration comes from the device's ``lv`` mask: a slot that was
-        resident when the scan was dispatched but masked at iteration j
-        (finished earlier in the chunk, or capacity-frozen) burned a dead
-        device iteration — logged in ``masked_rids``, never charged as
-        live batch occupancy."""
-        with obs.span("decode.dispatch"):
-            left = np.zeros((self.b,), np.int32)
-            resident = {}               # slot index -> rid at dispatch time
-            for i, info in self.slot_mgr.active_slots():
-                left[i] = min(info.payload.remaining, width)
-                resident[i] = info.rid
-            emitted, live, finite, held, self.cur_tok, self.caches, \
-                self.cache_len = self._get_loop(width)(
-                    self.params, self.cur_tok, self.caches, self.cache_len,
-                    jnp.asarray(left))
-        counted = self.cfg.is_moe and obs.TRACER.on
-        with obs.span("decode.sync"):
-            em, lv, fin, held = jax.device_get(
-                (emitted, live, finite, held if counted else None))
-        if counted:
-            obs.count("decode.held_routings", int(held.sum()))
-        with obs.span("decode.commit"):
-            return self._commit_chunk(width, resident, em, lv, fin)
+            width = self._effective_chunk(refill_pending)
+            with obs.span("decode.dispatch"):
+                left = np.zeros((self.b,), np.int32)
+                resident = {}           # slot index -> rid at dispatch time
+                for i, info in self.slot_mgr.active_slots():
+                    left[i] = info.payload.remaining
+                    resident[i] = info.rid
+                accepted = finite = held = None
+                if self.use_mtp:
+                    self.key, sub = jax.random.split(self.key)
+                    (emitted, accepted, live, self.cur_tok, self.draft_tok,
+                     self.caches, self.cache_len) = self._get_loop_mtp(width)(
+                        self.params, self.mtp_params, self.cur_tok,
+                        self.draft_tok, self.caches, self.cache_len,
+                        jnp.asarray(left), sub)
+                else:
+                    emitted, live, finite, held, self.cur_tok, self.caches, \
+                        self.cache_len = self._get_loop(width)(
+                            self.params, self.cur_tok, self.caches,
+                            self.cache_len, jnp.asarray(left))
+            counted = held is not None and self.cfg.is_moe and obs.TRACER.on
+            with obs.span("decode.sync"):
+                em, acc, lv, fin, held = jax.device_get(
+                    (emitted, accepted, live, finite,
+                     held if counted else None))
+            if counted:
+                obs.count("decode.held_routings", int(held.sum()))
+            if acc is None:
+                em, acc = em[:, :, None], np.zeros(lv.shape, bool)
+            if fin is None:
+                fin = np.ones(lv.shape, bool)
+            with obs.span("decode.commit"):
+                return self._commit_chunk(width, resident, em, acc, lv, fin)
 
     def _commit_chunk(self, width: int, resident: dict, em: np.ndarray,
-                      lv: np.ndarray, fin: np.ndarray) -> Tuple[
-            List[RequestResult],
-            List[Tuple[List[int], List[int], dict, List[int]]]]:
+                      acc: np.ndarray, lv: np.ndarray, fin: np.ndarray
+                      ) -> Tuple[List[RequestResult],
+                                 List[Tuple[List[int], List[int],
+                                            dict, List[int]]]]:
         """The host's side of one scanned chunk: each live slot's tokens
-        appended iteration by iteration, finished slots released."""
+        appended iteration by iteration, finished slots released.
+
+        ``em`` is (B, width, k): k is 1 without MTP, 2 with it. ``acc``
+        (B, width) marks an accepted MTP draft (all false without MTP): the
+        slot then wrote two KV rows, and the draft token ``em[i, j, 1]``
+        is credited only while the request still wants tokens. ``lv`` is
+        the device's live mask and ``fin`` says a step's logits row was
+        finite."""
         finished: List[RequestResult] = []
         iter_log: List[Tuple[List[int], List[int], dict, List[int]]] = []
         for j in range(width):
@@ -615,73 +553,19 @@ class DecodeEngine:
                 slot: _Slot = info.payload
                 slot.result.decode_iters += 1
                 slot.result.nonfinite_logits += int(not fin[i, j])
-                self.slot_mgr.advance(i, 1)
-                slot.result.tokens.append(int(em[i, j]))
+                slot.result.tokens.append(int(em[i, j, 0]))
                 slot.remaining -= 1
-                live_rids.append(rid)
                 tokens_by_rid[rid] = 1
-                if slot.remaining <= 0:
-                    fin_this.append(slot.result)
-                    self.slot_mgr.release(i)
-            self.live_slot_iters += len(live_rids)
-            self.dead_slot_iters += len(masked_rids)
-            iter_log.append((live_rids, [r.rid for r in fin_this],
-                             tokens_by_rid, masked_rids))
-            finished.extend(fin_this)
-        self._raise_if_capacity_frozen(lv)
-        return finished, iter_log
-
-    def _step_chunked_mtp(self, width: int) -> Tuple[
-            List[RequestResult],
-            List[Tuple[List[int], List[int], dict, List[int]]]]:
-        """Scanned MTP fast path: ``width`` speculative iterations — up to
-        ``2*width`` tokens — per host sync. Per-iteration accept/reject
-        ran on-device; here the emitted runs are committed slot by slot,
-        mirroring the per-step MTP accounting (advance 2 on accept, credit
-        the accepted draft token only while the request still wants
-        tokens). Live/masked attribution follows the device ``lv`` mask
-        exactly as in :meth:`_step_chunked`."""
-        left = np.zeros((self.b,), np.int32)
-        resident = {}                   # slot index -> rid at dispatch time
-        for i, info in self.slot_mgr.active_slots():
-            left[i] = info.payload.remaining
-            resident[i] = info.rid
-        self.key, sub = jax.random.split(self.key)
-        (emitted, accepted, live, self.cur_tok, self.draft_tok, self.caches,
-         self.cache_len) = self._get_loop_mtp(width)(
-            self.params, self.mtp_params, self.cur_tok, self.draft_tok,
-            self.caches, self.cache_len, jnp.asarray(left), sub)
-        em = np.asarray(emitted)        # (B, width, 2)
-        acc = np.asarray(accepted)      # (B, width)
-        lv = np.asarray(live)           # (B, width)
-
-        finished: List[RequestResult] = []
-        iter_log: List[Tuple[List[int], List[int], dict, List[int]]] = []
-        for j in range(width):
-            self.iters += 1
-            live_rids: List[int] = []
-            masked_rids: List[int] = []
-            fin_this: List[RequestResult] = []
-            tokens_by_rid: dict = {}
-            for i, rid in resident.items():
-                if not lv[i, j]:
-                    masked_rids.append(rid)
-                    continue
-                info = self.slot_mgr.get(i)   # live => not yet released
-                slot: _Slot = info.payload
-                slot.result.decode_iters += 1
-                self.slot_mgr.advance(i, 2 if acc[i, j] else 1)
-                new_toks = [int(em[i, j, 0])]
-                if acc[i, j] and slot.remaining > 1:
-                    new_toks.append(int(em[i, j, 1]))
-                committed = 0
-                for t in new_toks:
+                if acc[i, j]:
+                    # MTP accepted the draft: two KV rows were written.
+                    self.slot_mgr.advance(i, 2)
                     if slot.remaining > 0:
-                        slot.result.tokens.append(t)
+                        slot.result.tokens.append(int(em[i, j, 1]))
                         slot.remaining -= 1
-                        committed += 1
+                        tokens_by_rid[rid] = 2
+                else:
+                    self.slot_mgr.advance(i, 1)
                 live_rids.append(rid)
-                tokens_by_rid[rid] = committed
                 if slot.remaining <= 0:
                     fin_this.append(slot.result)
                     self.slot_mgr.release(i)
@@ -696,8 +580,7 @@ class DecodeEngine:
     def _raise_if_capacity_frozen(self, lv: np.ndarray) -> None:
         """Enforce the capacity invariant the masked device loop would
         otherwise hide: a slot that still wants tokens but was never live
-        this chunk is capacity-frozen — fail fast like per-step decode
-        does via DecodeSlotManager.advance, instead of livelocking."""
+        this chunk is capacity-frozen — fail fast instead of livelocking."""
         for i, info in list(self.slot_mgr.active_slots()):
             if info.payload.remaining > 0 and not lv[i].any():
                 raise SlotError(
@@ -794,13 +677,16 @@ class ServingSystem:
                  hit_aware_admission: Optional[bool] = None,
                  scheduler_config: Optional[SchedulerConfig] = None,
                  fault_injector: Optional[FaultInjector] = None):
+        if continuous_batching is False:
+            raise ValueError(
+                "the wave-shaped decode mode (continuous_batching=False) was "
+                "removed: every decode turn picks an adaptive scan width")
         self.cfg = cfg
         self.cc = context_cache
         overrides = {k: v for k, v in (
             ("policy", policy), ("tpot_budget_ms", tpot_budget_ms),
             ("admission", admission), ("interleave_microbatches", interleave),
             ("decode_chunk", decode_chunk),
-            ("continuous_batching", continuous_batching),
             ("decode_policy", decode_router),
             ("decode_rebalance_every", decode_rebalance_every),
             ("autoscale", autoscale),
@@ -915,9 +801,6 @@ class ServingSystem:
             raise ValueError(
                 "decode_chunk is baked into the jitted decode loop at "
                 "ServingSystem construction; build a new system to change it")
-        # continuous_batching is deliberately NOT baked: adaptive widths
-        # jit lazily per width, so flipping it between waves only warms
-        # additional scan programs on demand.
         if new.use_mtp != self.decode.use_mtp:
             raise ValueError(
                 "use_mtp is baked into the decode engine at ServingSystem "
@@ -1436,10 +1319,10 @@ class ServingSystem:
             the interactive tier first (FIFO within the class), then the
             batch tier only if no gate-ready interactive request is still
             blocked — batch never delays a gate-ready interactive request.
-            Runs once per wave boundary, and — under continuous batching —
-            again after each engine's chunk drains (``mid_turn``), so a
-            freed slot takes the next admission before the next engine
-            steps instead of waiting out the whole turn."""
+            Runs once per loop turn, and again after each engine's chunk
+            drains (``mid_turn``), so a freed slot takes the next admission
+            before the next engine steps instead of waiting out the whole
+            turn."""
             nonlocal waiting
             if not self.pool.live_ids:
                 # Total capacity loss. With an autoscaler the respawn path
@@ -1458,8 +1341,12 @@ class ServingSystem:
             # batch-age shed. At equal queue age the batch-tier backlog is
             # cut before any interactive request — interactive over-age
             # sheds only in a round with no over-age batch left. Recovered/
-            # preempted items are exempt (replay identity).
-            if degrade is not None or sched.brownout_level >= 3:
+            # preempted items are exempt (replay identity). It runs once per
+            # loop turn, not in the mid-turn rounds: a second round at the
+            # same instant would shed the interactive tier right after the
+            # batch tier.
+            if not mid_turn and (degrade is not None
+                                 or sched.brownout_level >= 3):
                 over_batch: List[_PendingAdmission] = []
                 over_inter: List[_PendingAdmission] = []
                 for item in waiting:
@@ -1589,26 +1476,21 @@ class ServingSystem:
                 sched.note_overload(any(
                     item_class(it) != "batch" and item_ready(it) <= now
                     for it in waiting))
-            # decode turn: decode_chunk device iterations per host sync on
-            # the fast path; every engine with active slots steps, and each
-            # engine's virtual clock is charged per iteration so trace/SLO
-            # semantics match per-step single-engine decode. Continuous
-            # batching steps engines individually (adaptive scan width) and
-            # re-runs admission after each engine's chunk drains, so freed
+            # decode turn: every engine with active slots runs one scan at
+            # its adaptive width, and each engine's virtual clock is charged
+            # per iteration so trace/SLO semantics match per-step decode.
+            # Admission re-runs after each engine's chunk drains, so freed
             # slots refill mid-turn — before the next engine steps — while
-            # per-engine clock charging and the autoscaler's demand signal
-            # (evaluated once per turn, below) stay exactly as in the
-            # wave-shaped loop.
+            # the autoscaler's demand signal is evaluated once per turn,
+            # below.
             if self.pool.active:
                 decode_turns += 1
-                continuous = sched.config.continuous_batching
                 stepped = []
                 for engine in list(self.pool.live_ids):
                     if not self.pool.engines[engine].active:
                         continue
                     finished, iter_log = self.pool.step_engine(
-                        engine, continuous=continuous,
-                        refill_pending=continuous and refill_imminent(engine))
+                        engine, refill_pending=refill_imminent(engine))
                     stepped.append(engine)
                     for entry in iter_log:
                         sched.on_decode_step(*entry, engine=engine)
@@ -1616,7 +1498,7 @@ class ServingSystem:
                         sched.on_finish(sched.traces[r.rid], len(r.tokens))
                         self._inflight.pop(r.rid, None)
                     results.extend(finished)
-                    if continuous and waiting:
+                    if waiting:
                         with obs.span("serve.admit"):
                             admit_waiting(mid_turn=True)
                 sched.sync_idle_clocks(stepped)

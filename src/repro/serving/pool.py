@@ -409,17 +409,15 @@ class DecodePool:
         self.router.on_admit(engine, block_keys)
 
     # -- stepping ----------------------------------------------------------
-    def step_engine(self, engine: int, continuous: bool = False,
+    def step_engine(self, engine: int,
                     refill_pending: bool = False) -> Tuple[list, list]:
-        """One host-sync chunk on a single engine (the continuous-batching
-        serve loop steps engines individually so freed slots can be
-        refilled *between* engine chunks within one decode turn).
-        ``continuous``/``refill_pending`` thread through to
-        :meth:`~repro.serving.engine.DecodeEngine.step_chunk`'s adaptive
-        chunk sizing. Returns ``(finished, iter_log)``."""
+        """One host-sync chunk on a single engine (the serve loop steps
+        engines individually so freed slots can be refilled *between*
+        engine chunks within one decode turn). ``refill_pending`` threads
+        through to :meth:`~repro.serving.engine.DecodeEngine.step_chunk`'s
+        adaptive chunk sizing. Returns ``(finished, iter_log)``."""
         eng = self.engines[engine]
-        finished, iter_log = eng.step_chunk(continuous=continuous,
-                                            refill_pending=refill_pending)
+        finished, iter_log = eng.step_chunk(refill_pending=refill_pending)
         for r in finished:
             self._request_keys.pop(r.rid, None)
         return finished, iter_log

@@ -14,8 +14,8 @@ of ``serving/engine.py`` into small, separately testable pieces:
   per-request ``cache_len`` accounting; raises on double assignment or
   capacity overflow instead of silently corrupting batch state.
 * :class:`AdmissionGate`      — projects the TPOT of the next decode batch
-  from a linear step-time model (t(B) = t_fixed + B·t_per_req, the same
-  decomposition ``bench_tpot_slo`` uses) and refuses admissions that would
+  from a linear step-time model (t(B) = t_fixed + B·t_per_req, the paper's
+  Table 5 decomposition) and refuses admissions that would
   push projected TPOT over the configured budget. ``mode="queue"`` holds the
   request until the batch drains; ``mode="shed"`` rejects it immediately.
 * :class:`SLOTracker`         — records per-request TTFT/TPOT and exposes
@@ -25,7 +25,7 @@ of ``serving/engine.py`` into small, separately testable pieces:
   can overlap the other's attention compute (paper §4.2.3).
 * :class:`RequestTrace` / :class:`Scheduler` — a structured per-request
   trace (arrival, prefill start/end, transfer seconds, decode iterations and
-  seconds) on a deterministic virtual timeline, consumable by benchmarks.
+  seconds) on a deterministic virtual timeline.
 
 Time model
 ----------
@@ -437,8 +437,7 @@ def decode_cost_from_roofline(record: Optional[Dict[str, Any]],
 
     ``record`` carries ``compute_s`` / ``memory_s`` / ``collective_s`` as
     written by ``launch/dryrun.py``; the serial roofline step time is
-    ``max(compute, memory) + collective`` (same formula as
-    ``benchmarks.common.step_time_from_record``). Falls back to the
+    ``max(compute, memory) + collective``. Falls back to the
     placeholder defaults when no record exists or the arch has no
     per-request KV traffic to decompose by."""
     if not record or kv_bytes_per_req <= 0 or batch_per_chip <= 0:
@@ -748,21 +747,14 @@ class SchedulerConfig:
         default_factory=DecodeCostModel)
     interleave_microbatches: bool = False
     n_micro: int = 2
-    # Decode iterations per host sync (model.decode_loop scan length).
-    # 1 = per-step decode; >1 trades admission/trace granularity (requests
-    # join and the clock is reconciled only at chunk boundaries) for host
-    # round-trips amortized over `decode_chunk` tokens.
+    # Widest decode scan per host sync (model.decode_loop scan length).
+    # Each turn the engine picks a pre-jitted width <= decode_chunk: it
+    # shrinks to where the shortest request ends or, when a gate-held
+    # admission could land in a free slot, to 1, and the serve loop refills
+    # freed slots after each engine's chunk drains. 1 = one iteration per
+    # host sync; larger widths amortize the host round-trip over up to
+    # `decode_chunk` tokens.
     decode_chunk: int = 1
-    # Continuous batching on the chunked fast path: before each device
-    # dispatch the engine shrinks the effective scan width (to a pre-jitted
-    # width <= decode_chunk) when min(remaining) across active slots is
-    # below the chunk or a gate-held admission could land in a free slot,
-    # and the serve loop refills freed slots immediately after each
-    # engine's chunk drains (mid-scan refill) instead of once per wave
-    # boundary. Token-identical to the wave-shaped loop; admissions land
-    # strictly earlier. Control-plane only (no re-jit), so it may be
-    # flipped between waves via reconfigure_scheduler.
-    continuous_batching: bool = False
     # MTP speculative decoding: charge the virtual clock the paper's ~1.44x
     # per-iteration verification cost while the admission gate credits
     # 1+accept tokens per iteration (a decode_cost with explicit MTP terms
@@ -931,8 +923,8 @@ class Scheduler:
         self._eng_tokens = [0] * self.n_decode
         # Dead-slot observability: slot-iterations that did work vs slot-
         # iterations burned masked (resident at dispatch, lv false), plus
-        # the number of admissions that landed mid-scan (continuous
-        # batching refills between engine chunks within one decode turn).
+        # the number of admissions that landed mid-scan (refills between
+        # engine chunks within one decode turn).
         self.live_slot_iters = 0
         self.masked_slot_iters = 0
         self._eng_masked = [0] * self.n_decode
@@ -1278,7 +1270,7 @@ class Scheduler:
 
     def note_mid_scan_refill(self) -> None:
         """An admission landed between engine chunks within one decode
-        turn (continuous batching) rather than at a wave boundary."""
+        turn rather than at the top of the serve loop."""
         self.mid_scan_refills += 1
 
     def advance_clock(self, t: float) -> None:
@@ -1495,8 +1487,8 @@ class Scheduler:
             s["tokens_per_decode_step"] = (self.decode_token_count
                                            / self.decode_steps)
         # Dead-slot observability: fraction of slot-iterations the device
-        # spent on resident-but-masked slots (continuous batching exists
-        # to drive this toward zero).
+        # spent on resident-but-masked slots (adaptive scan widths keep it
+        # at zero unless a slot is capacity-frozen).
         occupied = self.live_slot_iters + self.masked_slot_iters
         s["live_slot_iters"] = self.live_slot_iters
         s["masked_slot_iters"] = self.masked_slot_iters

@@ -255,7 +255,7 @@ class KVTransferEngine:
         """Cross-engine decode KV migration rides the same isolated plane
         as the prefill→decode handoff (it must never contend with decode
         compute traffic), accounted separately so pool rebalancing cost is
-        visible in benchmarks."""
+        visible on its own."""
         dt, nbytes = self._deliver(payload, "migrate", rid, chunk)
         self.migrations += 1
         self.bytes_migrated += nbytes

@@ -1,10 +1,10 @@
 """Continuous batching in the scanned decode loop: adaptive chunk widths +
-mid-scan slot refill must emit bit-identical tokens to per-step decode
-(chunk-split invariance of `model.decode_loop`), never admit later than the
-wave-shaped chunked loop, and drive the dead-slot rate — masked iterations
-burned on resident-but-finished slots — measurably down. Also covers the
-satellite accounting fixes: masked-iteration attribution in the trace,
-unified shed semantics (gate shed == capacity reject: no tokens delivered),
+mid-scan slot refill must emit bit-identical tokens at width 4 and width 1
+(chunk-split invariance of `model.decode_loop`), never admit later than
+width 1, and plan no dead iterations — masked iterations burned on
+resident slots — of their own. Also covers the accounting around it:
+masked-iteration attribution in the trace (a capacity-frozen slot), unified
+shed semantics (gate shed == capacity reject: no tokens delivered),
 shed-inclusive queue percentiles, and full-prompt shared prefixes in the
 synthetic workload."""
 import jax
@@ -14,7 +14,7 @@ import pytest
 from conftest import smoke
 from repro.core import init_mtp_params
 from repro.models import init_params
-from repro.serving import (Request, SchedulerConfig, ServingSystem,
+from repro.serving import (Request, ServingSystem, SlotError,
                            poisson_requests)
 from repro.serving.scheduler import RequestTrace, SLOTracker
 
@@ -37,17 +37,16 @@ def _clone(reqs):
             for r in reqs]
 
 
-def _serve(params, cfg, reqs, *, chunk, cb, open_loop, **kw):
+def _serve(params, cfg, reqs, *, chunk, open_loop, **kw):
     kw.setdefault("decode_batch", 2)
     system = ServingSystem(params, cfg, n_prefill=2, capacity=32,
-                           decode_chunk=chunk,
-                           continuous_batching=cb or None, **kw)
+                           decode_chunk=chunk, **kw)
     results = system.serve(_clone(reqs), open_loop=open_loop)
     return {r.rid: r for r in results}, system.scheduler
 
 
 # ---------------------------------------------------------------------------
-# Tentpole: token identity of the continuous path vs per-step decode
+# Token identity of width-4 scans vs width-1 scans
 # ---------------------------------------------------------------------------
 
 
@@ -55,16 +54,14 @@ def _serve(params, cfg, reqs, *, chunk, cb, open_loop, **kw):
                                   "deepseek-r1",     # MLA latent cache
                                   "olmoe-1b-7b"])    # MoE
 def test_cb_token_identical_to_per_step(arch):
-    """Adaptive chunks + mid-scan refill emit the same tokens as per-step
-    decode, closed AND open loop, with identical per-request decode_iters
+    """Adaptive chunks + mid-scan refill emit the same tokens as width-1
+    scans, closed AND open loop, with identical per-request decode_iters
     (masked iterations must not leak into the trace)."""
     cfg, params = model(arch)
     reqs = _burst()
     for open_loop in (False, True):
-        ref, _ = _serve(params, cfg, reqs, chunk=1, cb=False,
-                        open_loop=open_loop)
-        out, sched = _serve(params, cfg, reqs, chunk=4, cb=True,
-                            open_loop=open_loop)
+        ref, _ = _serve(params, cfg, reqs, chunk=1, open_loop=open_loop)
+        out, sched = _serve(params, cfg, reqs, chunk=4, open_loop=open_loop)
         assert set(out) == set(ref)
         for rid in ref:
             assert out[rid].tokens == ref[rid].tokens, (arch, open_loop, rid)
@@ -81,10 +78,10 @@ def test_cb_token_identical_with_mtp():
     mtp = init_mtp_params(jax.random.PRNGKey(2), cfg)
     reqs = _burst(n=6)
     for open_loop in (False, True):
-        ref, _ = _serve(params, cfg, reqs, chunk=1, cb=False,
-                        open_loop=open_loop, use_mtp=True, mtp_params=mtp)
-        out, _ = _serve(params, cfg, reqs, chunk=4, cb=True,
-                        open_loop=open_loop, use_mtp=True, mtp_params=mtp)
+        ref, _ = _serve(params, cfg, reqs, chunk=1, open_loop=open_loop,
+                        use_mtp=True, mtp_params=mtp)
+        out, _ = _serve(params, cfg, reqs, chunk=4, open_loop=open_loop,
+                        use_mtp=True, mtp_params=mtp)
         for rid in ref:
             assert out[rid].tokens == ref[rid].tokens, (open_loop, rid)
             assert out[rid].decode_iters == ref[rid].decode_iters
@@ -93,14 +90,13 @@ def test_cb_token_identical_with_mtp():
 def test_cb_mid_scan_refill_on_autoscaled_pool():
     """A refill landing mid-wave on a pooled + autoscaled run: freed slots
     are refilled between engine chunks (mid_scan_refills > 0) and the
-    tokens still match a per-step autoscaled serve bit-exactly."""
+    tokens still match a width-1 autoscaled serve bit-exactly."""
     cfg, params = model("granite-3-2b")
     reqs = _burst(n=10, rate=400.0, seed=5)
     pool_kw = dict(decode_engines=1, autoscale=True, min_engines=1,
                    max_engines=3)
-    ref, _ = _serve(params, cfg, reqs, chunk=1, cb=False, open_loop=True,
-                    **pool_kw)
-    out, sched = _serve(params, cfg, reqs, chunk=4, cb=True, open_loop=True,
+    ref, _ = _serve(params, cfg, reqs, chunk=1, open_loop=True, **pool_kw)
+    out, sched = _serve(params, cfg, reqs, chunk=4, open_loop=True,
                         **pool_kw)
     for rid in ref:
         assert out[rid].tokens == ref[rid].tokens, rid
@@ -111,75 +107,91 @@ def test_cb_mid_scan_refill_on_autoscaled_pool():
     assert sum(s["engine_masked_iters"]) == s["masked_slot_iters"]
 
 
-def test_cb_is_control_plane_flippable():
-    """continuous_batching is deliberately NOT baked: widths jit lazily,
-    so reconfigure_scheduler can flip it between waves on one system."""
+def test_wave_shaped_mode_is_rejected():
+    """The wave-shaped decode mode is gone: asking for it raises instead of
+    silently serving with adaptive widths."""
     cfg, params = model("qwen3-8b")
-    reqs = _burst(n=4)
-    system = ServingSystem(params, cfg, n_prefill=2, decode_batch=2,
-                           capacity=32, decode_chunk=4)
-    off = {r.rid: r.tokens for r in system.serve(_clone(reqs))}
-    system.reconfigure_scheduler(SchedulerConfig(decode_chunk=4,
-                                                 continuous_batching=True))
-    on = {r.rid: r.tokens for r in system.serve(_clone(reqs))}
-    assert on == off
+    with pytest.raises(ValueError, match="wave-shaped"):
+        ServingSystem(params, cfg, n_prefill=1, decode_batch=2, capacity=32,
+                      decode_chunk=4, continuous_batching=False)
 
 
 # ---------------------------------------------------------------------------
-# Tentpole acceptance: dead-slot rate down, admissions never later
+# No dead iterations, admissions never later
 # ---------------------------------------------------------------------------
 
 
 def test_cb_lowers_dead_slot_rate_and_never_admits_later():
-    """Identical arrival trace through the wave-shaped chunked loop vs the
-    continuous path: same tokens, measurably lower dead-slot rate, no
-    request admitted later, and the TPOT gate still holds."""
+    """Identical arrival trace through width-1 and width-4 scans: same
+    tokens, no dead iterations at either width (the width snaps down to
+    where the shortest request ends), mid-scan refills, no request admitted
+    later at width 4 than at width 1, and the TPOT gate still holds."""
     cfg, params = model("granite-3-2b")
-    # max_new=6 -> 5 decode iters, != 0 mod chunk 4: the wave-shaped loop
-    # provably burns masked tail iterations on the shortest slot.
+    # max_new=6 -> 5 decode iters, != 0 mod chunk 4: a fixed width-4 scan
+    # would burn masked tail iterations on the shortest slot.
     reqs = _burst(n=8, rate=300.0, max_new=6)
     kw = dict(open_loop=True, decode_batch=3, tpot_budget_ms=9.0,
               admission="queue")
-    off, s_off = _serve(params, cfg, reqs, chunk=4, cb=False, **kw)
-    on, s_on = _serve(params, cfg, reqs, chunk=4, cb=True, **kw)
-    for rid in off:
-        assert on[rid].tokens == off[rid].tokens, rid
-    so, sn = s_off.summary(), s_on.summary()
-    assert so["dead_slot_rate"] > 0.0            # the bug is observable
-    assert sn["dead_slot_rate"] < so["dead_slot_rate"]
+    one, s_one = _serve(params, cfg, reqs, chunk=1, **kw)
+    four, s_four = _serve(params, cfg, reqs, chunk=4, **kw)
+    for rid in one:
+        assert four[rid].tokens == one[rid].tokens, rid
+    so, sn = s_one.summary(), s_four.summary()
+    assert so["dead_slot_rate"] == sn["dead_slot_rate"] == 0.0
     assert sn["mid_scan_refills"] > 0
     assert sn["tpot_max_s"] <= 9.0e-3 + 1e-12    # gate never violated
-    for rid, tr in s_on.traces.items():
-        assert tr.decode_admit <= s_off.traces[rid].decode_admit + 1e-12
+    for rid, tr in s_four.traces.items():
+        assert tr.decode_admit <= s_one.traces[rid].decode_admit + 1e-12
 
 
 # ---------------------------------------------------------------------------
-# Satellite 1: masked-iteration attribution in the trace
+# Masked-iteration attribution in the trace
 # ---------------------------------------------------------------------------
 
 
 def test_masked_iterations_attributed_not_charged():
-    """With chunk 4 and max_new 6 the wave-shaped loop dispatches masked
-    iterations; they must land in trace.masked_iters — NOT in
-    decode_iters, decode_seconds, or the virtual clock."""
+    """A capacity-frozen slot is masked inside a width-4 scan: its masked
+    iterations must land in trace.masked_iters — NOT in decode_iters,
+    decode_seconds, or the virtual clock — and the next turn, in which it
+    is never live, raises instead of livelocking."""
     cfg, params = model("granite-3-2b")
+    system = ServingSystem(params, cfg, n_prefill=1, decode_batch=2,
+                           capacity=16, decode_chunk=4)
+    sched = system.scheduler
     rng = np.random.RandomState(3)
-    reqs = [Request(i, list(rng.randint(0, 100, 10)), 6) for i in range(4)]
-    out, sched = _serve(params, cfg, reqs, chunk=4, cb=False,
-                        open_loop=False, decode_batch=3)
-    recs = {r["rid"]: r for r in sched.trace_records()}
-    for rid, r in out.items():
-        assert recs[rid]["decode_iters"] == r.decode_iters == 5
-        assert recs[rid]["tokens_out"] == 6
+    # rid 0 has room for every token; rid 1 (prompt 14, capacity 16) for
+    # two decode writes. The serve loop rejects rid 1 up front, so it is
+    # placed the way the loop places an admitted request.
+    for rid, plen in ((0, 6), (1, 14)):
+        req = Request(rid, list(rng.randint(0, 100, plen)), 8)
+        trace = sched.on_arrival(rid, 0.0, plen)
+        first, caches, res = system.prefills[0].run(req)
+        sched.on_prefill_done(trace, 0, res.computed_tokens,
+                              res.reused_tokens)
+        sched.on_transfer(trace, system.transfer.transfer(caches, rid=rid))
+        slot = system.pool.engines[0].free_slot()
+        system.pool.add(0, slot, caches, first, plen, res, 8)
+        sched.on_admit(trace, slot, 0)
+    clock0 = sched.engine_clock(0)
+    finished, iter_log = system.pool.step_engine(0)
+    assert finished == [] and len(iter_log) == 4
+    assert [masked for *_, masked in iter_log] == [[], [], [1], [1]]
+    for entry in iter_log:
+        sched.on_decode_step(*entry, engine=0)
+    recs = {r.rid: r for r in sched.traces.values()}
+    assert (recs[0].decode_iters, recs[0].masked_iters) == (4, 0)
+    assert (recs[1].decode_iters, recs[1].masked_iters) == (2, 2)
     s = sched.summary()
-    assert s["masked_slot_iters"] > 0
-    assert sum(rec["masked_iters"] for rec in recs.values()) \
-        == s["masked_slot_iters"]
-    # masked iterations charge zero virtual time: total decode time equals
-    # the per-iteration charge over live batch sizes only
-    assert s["dead_slot_rate"] == pytest.approx(
-        s["masked_slot_iters"]
-        / (s["masked_slot_iters"] + s["live_slot_iters"]))
+    assert s["masked_slot_iters"] == 2 and s["live_slot_iters"] == 6
+    assert s["dead_slot_rate"] == pytest.approx(2 / 8)
+    # masked iterations charge zero virtual time: the clock and each trace
+    # are charged per iteration over the live batch only
+    cost = sched.cost
+    assert sched.engine_clock(0) - clock0 == pytest.approx(
+        2 * cost.step_time(2) + 2 * cost.step_time(1))
+    assert recs[1].decode_seconds == pytest.approx(2 * cost.step_time(2))
+    with pytest.raises(SlotError, match="capacity"):
+        system.pool.step_engine(0)
 
 
 # ---------------------------------------------------------------------------

@@ -328,7 +328,7 @@ def test_prefill_engine_inserts_ems_hit_in_one_call(qwen, monkeypatch):
 
 
 def test_serving_decode_chunk_token_identical(qwen):
-    """decode_chunk >= 4 emits token-identical output to per-step decode,
+    """decode_chunk >= 4 emits token-identical output to width-1 scans,
     with identical per-request decode_iters in the trace."""
     cfg, params = qwen
     rng = np.random.RandomState(9)
@@ -350,10 +350,10 @@ def test_serving_decode_chunk_token_identical(qwen):
 
 @pytest.mark.parametrize("chunk", [1, 4])
 def test_serving_counts_nonfinite_logits(qwen, chunk):
-    """Every served logits row is inspected, on the per-step and the scanned
-    decode path: none with finite weights; all of them (the prompt's last
-    position and each decode step) with a NaN final norm, which leaves the
-    KV caches, and so decoding itself, intact."""
+    """Every served logits row is inspected, at scan widths 1 and 4: none
+    with finite weights; all of them (the prompt's last position and each
+    decode step) with a NaN final norm, which leaves the KV caches, and so
+    decoding itself, intact."""
     cfg, params = qwen
     reqs = [Request(i, list(range(1 + i, 13 + i)), 6) for i in range(3)]
     nan_norm = dict(params, final_norm=jnp.full_like(params["final_norm"],
@@ -391,8 +391,7 @@ def test_serving_decode_chunk_with_reuse_and_trace(qwen):
 
 def test_chunked_engine_raises_on_capacity_frozen_slot(qwen):
     """A slot that hits cache capacity with tokens still requested must
-    raise SlotError on the chunked path (like per-step decode via
-    DecodeSlotManager.advance), never livelock silently."""
+    raise SlotError, never livelock silently."""
     from repro.serving import DecodeEngine, RequestResult, SlotError
     from repro.serving.cache_ops import slice_request
 

@@ -437,3 +437,7 @@ def test_ems_session_soak_through_serving_system():
     assert np.mean(turn_frac[0]) == 0.0
     assert np.mean(turn_frac[2]) > np.mean(turn_frac[0])
     assert ems.ems_stats()["hit_rate"] > 0
+    # promote/demote bytes agree with the RDMA-plane transfer books
+    ems.flush()
+    assert ems.demote_bytes == ems.transfer.bytes_demoted > 0
+    assert ems.promote_bytes == ems.transfer.bytes_promoted

@@ -193,13 +193,13 @@ def test_can_fuse_verify_gating():
 
 
 # ---------------------------------------------------------------------------
-# Serving end-to-end: chunked MTP == per-step MTP
+# Serving end-to-end: width-4 MTP scans == width-1 MTP scans
 # ---------------------------------------------------------------------------
 
 
 def test_serving_mtp_chunked_token_identical(qwen):
     """use_mtp + decode_chunk=4 emits token-identical output (and identical
-    per-request iteration counts) to per-step MTP serving."""
+    per-request iteration counts) to width-1 MTP serving."""
     cfg, params, mtp = qwen
     rng = np.random.RandomState(9)
     prompts = [list(rng.randint(0, 200, 12)) for _ in range(5)]

@@ -46,8 +46,7 @@ def serve(cfg, params):
     cc = EMSService(MemoryPool(n_nodes=2), block_tokens=8,
                     model_tag=cfg.name)
     system = ServingSystem(params, cfg, n_prefill=2, decode_batch=2,
-                           capacity=48, context_cache=cc, decode_chunk=4,
-                           continuous_batching=True)
+                           capacity=48, context_cache=cc, decode_chunk=4)
     results = system.serve(requests())
     return {r.rid: r.tokens for r in results if not r.shed}
 

@@ -314,6 +314,7 @@ def test_streamed_ttft_monotonically_better(granite):
     assert [r.tokens for r in strm_res] == [r.tokens for r in sync_res]
     assert all(strm[r] <= sync[r] + 1e-12 for r in sync)
     assert any(strm[r] < sync[r] - 1e-12 for r in sync)
+    assert np.median(list(strm.values())) < np.median(list(sync.values()))
     assert system.scheduler.summary()["stream_overlap_s"] > 0
 
 

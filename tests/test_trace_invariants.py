@@ -268,11 +268,12 @@ def _overload_requests(seed=7, n_batch=6, n_interactive=4):
     return reqs
 
 
-def test_preempt_resume_token_identical_and_monotone(granite):
-    """A preempted-then-resumed batch request finishes token-identical to
-    the uncontended run, its per-request clock stays monotone through the
-    preemption, and DecodeSlotManager acquired/released conservation holds
-    across every evict/re-admit cycle."""
+@pytest.fixture(scope="module")
+def overload_runs(granite):
+    """The overload burst served with class-aware control (6 ms interactive
+    budget, 30 ms batch budget, batch preemption) and class-blind with the
+    gate open: ``(controlled system, its results, blind system, its
+    results)``."""
     cfg, params = granite
     reqs = _overload_requests()
 
@@ -285,8 +286,15 @@ def test_preempt_resume_token_identical_and_monotone(granite):
         results = system.serve(list(reqs), open_loop=True)
         return system, results
 
-    controlled, res_c = run(class_aware=True)
-    reference, res_r = run(class_aware=False)
+    return (*run(class_aware=True), *run(class_aware=False))
+
+
+def test_preempt_resume_token_identical_and_monotone(overload_runs):
+    """A preempted-then-resumed batch request finishes token-identical to
+    the uncontended run, its per-request clock stays monotone through the
+    preemption, and DecodeSlotManager acquired/released conservation holds
+    across every evict/re-admit cycle."""
+    controlled, res_c, _, res_r = overload_runs
     sched = controlled.scheduler
     preempted = [t.rid for t in sched.traces.values() if t.preemptions > 0]
     assert preempted, "scenario must actually preempt"
@@ -314,13 +322,30 @@ def test_preempt_resume_token_identical_and_monotone(granite):
     assert sched.tracker.summary()["shed"] == 0
 
 
+def test_class_aware_control_holds_interactive_tpot_p99(overload_runs):
+    """On the identical overload burst, class-aware control (per-class
+    budgets, strict priority, batch preemption) holds the interactive TPOT
+    p99 inside its 6 ms budget, which the class-blind run breaks, and every
+    request is served."""
+    controlled, res_c, blind, res_b = overload_runs
+
+    def interactive_p99_ms(system):
+        s = system.scheduler.summary()
+        return s.get("classes", {}).get("interactive", s)["tpot_p99_s"] * 1e3
+
+    assert interactive_p99_ms(controlled) <= 6.0 + 1e-6
+    assert interactive_p99_ms(blind) > 6.0 + 1e-6
+    assert controlled.scheduler.summary()["preemptions"] >= 1
+    assert len(res_c) == len(res_b) == 10
+    assert not any(r.shed for r in res_c + res_b)
+
+
 def test_preempt_composes_with_continuous_batching_and_chunk(granite):
-    """Preemption through the chunked continuous-batching fast path keeps
-    the same invariants: conservation, monotone traces, completion."""
+    """Preemption through width-2 adaptive scans keeps the same
+    invariants: conservation, monotone traces, completion."""
     cfg, params = granite
     system = ServingSystem(params, cfg, n_prefill=2, decode_batch=3,
                            capacity=64, decode_chunk=2,
-                           continuous_batching=True,
                            tpot_budget_ms=6.0, batch_tpot_budget_ms=30.0,
                            preempt_batch=True)
     results = system.serve(_overload_requests(seed=23), open_loop=True)
